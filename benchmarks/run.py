@@ -35,6 +35,7 @@ from benchmarks import (
     exp14_ivm,
     kernels_micro,
 )
+from repro.compile_cache import use_compile_cache
 
 MODULES = [
     exp1_runtime_imputations,
@@ -78,6 +79,7 @@ def main(argv=None) -> int:
     ap.add_argument("--no-history", action="store_true",
                     help="do not append to the timing history")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     only = set(args.only.split(",")) if args.only else None
     all_results = {}

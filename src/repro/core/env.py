@@ -136,9 +136,10 @@ ENV_REGISTRY: Dict[str, EnvKnob] = _registry(
             owner="imputers/base.py"),
     EnvKnob("QUIP_JOIN_IMPL", "choice", "numpy (engine) / auto (kernel)",
             "join-spine dispatch: numpy sort-join oracle, jnp ref, or the "
-            "Pallas open-addressing kernels; unset means numpy in the "
-            "engine (core/triggers.py) and the backend default in the "
-            "kernel wrapper (kernels/ops.py)",
+            "Pallas open-addressing kernels (PALLAS_RUNS runs ref for "
+            "them on TPU); unset means numpy in the engine "
+            "(core/triggers.py) and the backend default in the kernel "
+            "wrapper (kernels/ops.py)",
             choices=("numpy", "ref", "pallas"),
             owner="core/triggers.py, kernels/ops.py"),
     EnvKnob("QUIP_KNN_IMPL", "choice", "numpy",
@@ -147,11 +148,15 @@ ENV_REGISTRY: Dict[str, EnvKnob] = _registry(
     EnvKnob("QUIP_SEGMENT_IMPL", "choice", "numpy",
             "grouped-aggregate segment-reduction dispatch",
             choices=("numpy", "ref", "pallas"), owner="kernels/ops.py"),
-    EnvKnob("QUIP_BLOOM_IMPL", "choice", "auto (pallas on TPU, ref on CPU)",
-            "bloom-probe dispatch for join pruning",
+    EnvKnob("QUIP_BLOOM_IMPL", "choice",
+            "auto (kernels/ops.py backend table: pallas on TPU, ref on CPU)",
+            "bloom-probe dispatch for join pruning; pallas runs what "
+            "kernels/ops.py PALLAS_RUNS names for the backend",
             choices=("numpy", "ref", "pallas"), owner="kernels/ops.py"),
-    EnvKnob("QUIP_DIST_IMPL", "choice", "auto (pallas on TPU, ref on CPU)",
-            "masked KNN partial-distance dispatch",
+    EnvKnob("QUIP_DIST_IMPL", "choice",
+            "auto (kernels/ops.py backend table: pallas on TPU, ref on CPU)",
+            "masked KNN partial-distance dispatch; pallas runs what "
+            "kernels/ops.py PALLAS_RUNS names for the backend",
             choices=("numpy", "ref", "pallas"), owner="kernels/ops.py"),
     EnvKnob("QUIP_EXEC_IMPL", "choice", "interp",
             "executor dispatch: morsel interpreter or compiled tensor "

@@ -3,10 +3,12 @@
 The reference matrix is the whole table (standardized numeric view, missing
 cells masked).  Inference computes partial L2 distances over co-observed
 dimensions — the imputation hot spot the paper measures (Fig. 2: KNN
-inference dominates query time) — via the Pallas masked-distance kernel on
-TPU (pure-jnp oracle on CPU; see ``repro.kernels``).  Neighbour aggregation
-(mean / categorical mode) is the vectorized ``kernels.ops.neighbor_aggregate``
-op, dispatched with ``QUIP_KNN_IMPL`` (numpy | ref | pallas).
+inference dominates query time) — via ``kernels.ops.masked_knn``: unset,
+the masked distance is the Mosaic-compiled Pallas kernel on TPU and the jnp
+``ref`` path on the CPU (``QUIP_DIST_IMPL``), and top-k runs on the same
+device.  Neighbour aggregation (mean / categorical mode) is the vectorized
+``kernels.ops.neighbor_aggregate`` op, dispatched with ``QUIP_KNN_IMPL``
+(numpy by default | ref | pallas).
 """
 
 from __future__ import annotations

@@ -118,7 +118,7 @@ def _build_kernel(keys_ref, slot_key_ref, slot_idx_ref, *, n: int,
 
 @functools.partial(jax.jit, static_argnames=("log2cap", "interpret"))
 def hash_join_build_pallas(
-    folded: jnp.ndarray, *, log2cap: int, interpret: bool = True
+    folded: jnp.ndarray, *, log2cap: int, interpret: bool
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """folded: (n,) uint32 build keys → (slot_key (cap,) uint32,
     slot_idx (cap,) int32) with ``slot_idx == -1`` marking empty slots."""
@@ -194,7 +194,7 @@ def hash_join_probe_pallas(
     *,
     log2cap: int,
     max_dup: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Probe the built table with (n,) uint32 keys.
 

@@ -28,6 +28,14 @@ __all__ = ["masked_distance_pallas"]
 BQ, BR, BK = 128, 128, 128
 
 
+def _dot_t(a, b):
+    """``a @ b.T`` in full f32: the MXU's default f32 precision is a single
+    bf16 pass, too coarse to rank neighbours as the f32 oracle does."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=jax.lax.Precision.HIGHEST)
+
+
 def _kernel(q_ref, qm_ref, r_ref, rm_ref, out_ref, *, d_total: int, nk: int):
     kidx = pl.program_id(2)
 
@@ -40,14 +48,10 @@ def _kernel(q_ref, qm_ref, r_ref, rm_ref, out_ref, *, d_total: int, nk: int):
     rv = r * rm
 
     # Fused partial sums for this feature chunk.
-    q2 = jax.lax.dot_general((qv * qv), rm, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    r2 = jax.lax.dot_general(qm, (rv * rv), (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    cross = jax.lax.dot_general(qv, rv, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    nco = jax.lax.dot_general(qm, rm, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
+    q2 = _dot_t(qv * qv, rm)
+    r2 = _dot_t(qm, rv * rv)
+    cross = _dot_t(qv, rv)
+    nco = _dot_t(qm, rm)
 
     sq = q2 + r2 - 2.0 * cross
 
@@ -84,7 +88,7 @@ def _pad_to(x, mult, axis, value=0.0):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def masked_distance_pallas(q, qm, r, rm, *, interpret: bool = True):
+def masked_distance_pallas(q, qm, r, rm, *, interpret: bool):
     """(nq, d) x (nr, d) → (nq, nr) scaled partial distances (float32)."""
     nq, d = q.shape
     nr = r.shape[0]

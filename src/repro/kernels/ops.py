@@ -3,26 +3,36 @@
 ``impl``:
   * ``"numpy"``   — pure-host port (no device round-trip; exact keys for
                     the join, float32 math for the distance).
-  * ``"ref"``     — pure-jnp oracle (fast XLA path on CPU; default here).
-  * ``"pallas"``  — the Pallas kernel.  On this CPU-only container it runs in
-                    interpret mode; on TPU it compiles to Mosaic.
+  * ``"ref"``     — pure-jnp oracle, compiled by XLA for the backend.
+  * ``"pallas"``  — the Pallas kernel.  What it runs is fixed per backend by
+                    :data:`PALLAS_RUNS`: on the CPU every kernel runs in
+                    Pallas interpret mode (a correctness tool, not a
+                    performance path); on TPU every kernel runs
+                    Mosaic-compiled except the hash join, which runs its
+                    ``ref`` path there (see ``docs/kernels.md``).
 
 Every public op resolves ``impl`` through a ``resolve_*_impl`` knob
 (``QUIP_<OP>_IMPL`` env) or forwards it to one that does — the quiplint
 kernel-parity pass (``python -m repro.analysis``) enforces this triple.
-The unset default is chosen per-backend: Pallas on TPU, ref on CPU
-(interpret-mode Pallas is a correctness tool, not a performance path).
+Unset, bloom probe and masked distance take :func:`default_impl` (Pallas
+on TPU, ref on CPU); join, neighbour aggregation and segment reduction
+default to numpy in the engine.  A backend that is neither CPU nor TPU
+raises: no path silently stands in for another.  :data:`KERNEL_CALLS`
+tallies which implementation each call actually ran.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import collections
+import functools
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
+from repro.analysis.lockcheck import make_lock
 from repro.core.env import env_choice
 from repro.kernels import ref as _ref
 from repro.kernels.bloom_probe import bloom_probe_pallas
@@ -37,6 +47,8 @@ from repro.kernels.neighbor_agg import neighbor_mean_pallas, neighbor_mode_palla
 from repro.kernels.segment_ops import segment_reduce_pallas
 
 __all__ = [
+    "KERNEL_CALLS",
+    "PALLAS_RUNS",
     "bloom_probe",
     "hash_join_match",
     "masked_distance",
@@ -51,40 +63,111 @@ __all__ = [
     "resolve_segment_impl",
 ]
 
+#: The unset kernel choice per backend.
+_DEFAULT_IMPL = {"cpu": "ref", "tpu": "pallas"}
+
+#: What ``impl="pallas"`` runs, per backend and op.  Mosaic lowers no
+#: vector gather from a VMEM array and no scalar-indexed update of one, and
+#: the hash-join build and probe need both, so on TPU the join runs its jnp
+#: ``ref`` path; ``tests/test_tpu_compile.py`` holds a strict-xfail compile
+#: test for each of the two kernels.
+PALLAS_RUNS: Dict[str, Dict[str, str]] = {
+    "cpu": {"bloom": "pallas", "dist": "pallas", "join": "pallas",
+            "knn": "pallas", "segment": "pallas"},
+    "tpu": {"bloom": "pallas", "dist": "pallas", "join": "ref",
+            "knn": "pallas", "segment": "pallas"},
+}
+
+
+def _backend() -> str:
+    backend = jax.default_backend()
+    if backend not in _DEFAULT_IMPL:
+        raise RuntimeError(
+            f"no kernel dispatch for JAX backend {backend!r} "
+            f"(known: {sorted(_DEFAULT_IMPL)})"
+        )
+    return backend
+
 
 def default_impl() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    """The unset kernel choice for this backend: Pallas on TPU, ref on CPU."""
+    return _DEFAULT_IMPL[_backend()]
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode runs on the CPU backend only."""
+    return _backend() == "cpu"
 
+
+class KernelCalls:
+    """Process-wide tally of kernel-layer calls, keyed
+    ``"<op>/<impl that ran>"`` with ``pallas-interpret`` for interpret
+    mode — the record of what actually ran on which path."""
+
+    def __init__(self):
+        self._lock = make_lock("KernelCalls._lock")
+        self._counts: collections.Counter = collections.Counter()  # guarded-by: _lock
+
+    @staticmethod
+    def key(op: str, impl: str) -> str:
+        if impl == "pallas" and _interpret():
+            impl = "pallas-interpret"
+        return f"{op}/{impl}"
+
+    def record(self, op: str, impl: str) -> None:
+        key = self.key(op, impl)
+        with self._lock:
+            self._counts[key] += 1
+
+    def snapshot(self) -> Dict[str, int]:
+        with self._lock:
+            return dict(self._counts)
+
+
+KERNEL_CALLS = KernelCalls()
 
 _HOST_IMPLS = ("numpy", "ref", "pallas")
 
 
+def _bucket(n: int, floor: int) -> int:
+    """Smallest power of two >= ``n`` (at least ``floor``).  Device paths
+    pad row counts to these sizes so that jit compiles a few shapes, not
+    one per ragged batch."""
+    return max(floor, 1 << max(n - 1, 0).bit_length())
+
+
+def _pad_rows(x: np.ndarray, rows: int, value=0) -> np.ndarray:
+    pad = rows - x.shape[0]
+    if pad == 0:
+        return x
+    widths = [(0, pad)] + [(0, 0)] * (x.ndim - 1)
+    return np.pad(x, widths, constant_values=value)
+
+
+def _runs(op: str, impl: str) -> str:
+    """Validate a chosen ``impl`` and map ``pallas`` to what runs here
+    (:data:`PALLAS_RUNS`)."""
+    if impl not in _HOST_IMPLS:
+        raise ValueError(f"unknown {op} impl {impl!r}")
+    return PALLAS_RUNS[_backend()][op] if impl == "pallas" else impl
+
+
 def resolve_bloom_impl(impl: Optional[str] = None) -> str:
     """Bloom-probe dispatch: explicit ``impl`` > ``QUIP_BLOOM_IMPL`` env >
-    the backend default (Pallas on TPU, ref elsewhere).  A *set* env value
-    is validated against numpy/ref/pallas; unset falls through to the
-    backend choice."""
-    if impl is not None:
-        if impl not in _HOST_IMPLS:
-            raise ValueError(f"unknown bloom impl {impl!r}")
-        return impl
-    impl = env_choice("QUIP_BLOOM_IMPL", _HOST_IMPLS, "auto")
-    return default_impl() if impl == "auto" else impl
+    the backend default (Pallas on TPU, ref on CPU)."""
+    if impl is None:
+        impl = env_choice("QUIP_BLOOM_IMPL", _HOST_IMPLS, "auto")
+        impl = default_impl() if impl == "auto" else impl
+    return _runs("bloom", impl)
 
 
 def resolve_dist_impl(impl: Optional[str] = None) -> str:
     """Masked-distance dispatch: explicit ``impl`` > ``QUIP_DIST_IMPL`` env
-    > the backend default (Pallas on TPU, ref elsewhere)."""
-    if impl is not None:
-        if impl not in _HOST_IMPLS:
-            raise ValueError(f"unknown distance impl {impl!r}")
-        return impl
-    impl = env_choice("QUIP_DIST_IMPL", _HOST_IMPLS, "auto")
-    return default_impl() if impl == "auto" else impl
+    > the backend default (Pallas on TPU, ref on CPU)."""
+    if impl is None:
+        impl = env_choice("QUIP_DIST_IMPL", _HOST_IMPLS, "auto")
+        impl = default_impl() if impl == "auto" else impl
+    return _runs("dist", impl)
 
 
 def resolve_join_impl(impl: Optional[str] = None) -> str:
@@ -94,12 +177,10 @@ def resolve_join_impl(impl: Optional[str] = None) -> str:
     oracle (``multi_match``) and never reaches this module; an explicit
     ``QUIP_JOIN_IMPL=ref|pallas`` routes the engine here, where the same
     knob then picks the kernel path."""
-    if impl is not None:
-        if impl not in _HOST_IMPLS:
-            raise ValueError(f"unknown join impl {impl!r}")
-        return impl
-    impl = env_choice("QUIP_JOIN_IMPL", _HOST_IMPLS, "auto")
-    return default_impl() if impl == "auto" else impl
+    if impl is None:
+        impl = env_choice("QUIP_JOIN_IMPL", _HOST_IMPLS, "auto")
+        impl = default_impl() if impl == "auto" else impl
+    return _runs("join", impl)
 
 
 def bloom_probe(
@@ -109,9 +190,10 @@ def bloom_probe(
     num_hashes: int,
     log2m: int,
     impl: Optional[str] = None,
-) -> jnp.ndarray:
+) -> np.ndarray:
     """``folded``: uint32 host-folded keys (see ``hashing.fold64``)."""
     impl = resolve_bloom_impl(impl)
+    KERNEL_CALLS.record("bloom_probe", impl)
     if impl == "numpy":
         # host multiply-shift probe — same uint32 wraparound math as
         # hashing.hash_positions_np, but over pre-folded keys
@@ -124,11 +206,15 @@ def bloom_probe(
         bit = pos & np.uint32(31)
         hit = (bits_np[word] >> bit) & np.uint32(1)
         return np.all(hit == 1, axis=1)
+    f = np.asarray(folded, dtype=np.uint32)
+    fp = _pad_rows(f, _bucket(len(f), 512))
     if impl == "pallas":
-        return bloom_probe_pallas(
-            bits, folded, num_hashes=num_hashes, log2m=log2m, interpret=_interpret()
+        out = bloom_probe_pallas(
+            bits, fp, num_hashes=num_hashes, log2m=log2m, interpret=_interpret()
         )
-    return _probe_ref_jit(bits, folded, num_hashes, log2m)
+    else:
+        out = _probe_ref_jit(bits, fp, num_hashes, log2m)
+    return np.asarray(out)[:len(f)]
 
 
 _probe_ref_jit = jax.jit(_ref.bloom_probe_ref, static_argnums=(2, 3))
@@ -163,6 +249,7 @@ def hash_join_match(
     if len(b) == 0 or len(p) == 0:
         z = np.zeros(0, dtype=np.int64)
         return z, z
+    KERNEL_CALLS.record("hash_join", impl)
     if impl == "numpy":
         return _hash_join_numpy(b, p)
     fb = fold64(b)
@@ -171,7 +258,8 @@ def hash_join_match(
     max_dup = int(np.unique(fb, return_counts=True)[1].max())
     # bound the dense (chunk × max_dup) match block; chunking the probe side
     # keeps memory flat on skewed builds while preserving probe-major order
-    chunk = max(256, _DENSE_BUDGET // max_dup)
+    # (a power of two, so only the last, ragged chunk is padded)
+    chunk = max(256, 1 << (max(1, _DENSE_BUDGET // max_dup).bit_length() - 1))
     # build once (table / sorted order), probe per chunk
     if impl == "pallas":
         log2cap = table_log2cap(len(b))
@@ -185,21 +273,24 @@ def hash_join_match(
     probe_parts, build_parts = [], []
     for lo in range(0, len(p), chunk):
         fpc = fp[lo:lo + chunk]
+        # bucketing the ragged last chunk bounds the shapes jit compiles;
+        # the padded probes' rows are sliced off before expansion
+        fpp = jnp.asarray(_pad_rows(fpc, _bucket(len(fpc), 256)))
         if impl == "pallas":
             counts, matches = hash_join_probe_pallas(
                 slot_key,
                 slot_idx,
-                jnp.asarray(fpc),
+                fpp,
                 log2cap=log2cap,
                 max_dup=max_dup,
                 interpret=_interpret(),
             )
         else:
             counts, matches = _hash_join_probe_sorted_jit(
-                sorted_keys, order, jnp.asarray(fpc), max_dup
+                sorted_keys, order, fpp, max_dup
             )
-        counts = np.asarray(counts, dtype=np.int64)
-        matches = np.asarray(matches)
+        counts = np.asarray(counts, dtype=np.int64)[:len(fpc)]
+        matches = np.asarray(matches)[:len(fpc)]
         # ragged expansion: row-major valid entries are already in oracle order
         probe_parts.append(
             np.repeat(np.arange(len(fpc), dtype=np.int64), counts) + lo
@@ -249,6 +340,7 @@ def masked_distance(
     impl: Optional[str] = None,
 ) -> jnp.ndarray:
     impl = resolve_dist_impl(impl)
+    KERNEL_CALLS.record("masked_distance", impl)
     if impl == "numpy":
         return _masked_distance_numpy(q, qm, r, rm)
     if impl == "pallas":
@@ -282,9 +374,21 @@ def masked_knn(
     k: int,
     *,
     impl: Optional[str] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    dmat = masked_distance(q, qm, r, rm, impl=impl)
-    neg, idx = jax.lax.top_k(-jnp.asarray(dmat), k)
+) -> Tuple[np.ndarray, np.ndarray]:
+    """Top-k smallest masked distances per query row.  Query rows are
+    padded to a power-of-two count; a padded row observes nothing, so its
+    distances are +inf, and it is sliced off."""
+    nq = q.shape[0]
+    rows = _bucket(nq, 128)
+    dmat = masked_distance(_pad_rows(np.asarray(q), rows),
+                           _pad_rows(np.asarray(qm), rows), r, rm, impl=impl)
+    dist, idx = _top_k_jit(jnp.asarray(dmat), k)
+    return np.asarray(dist)[:nq], np.asarray(idx)[:nq]
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _top_k_jit(dmat: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    neg, idx = jax.lax.top_k(-dmat, k)
     return -neg, idx
 
 
@@ -292,22 +396,18 @@ def resolve_knn_impl(impl: Optional[str] = None) -> str:
     """KNN-aggregation dispatch: explicit ``impl`` > ``QUIP_KNN_IMPL`` env >
     ``"numpy"`` (the vectorized host oracle, bit-identical to the seed
     per-row loop)."""
-    if impl is not None:
-        if impl not in _HOST_IMPLS:
-            raise ValueError(f"unknown knn impl {impl!r}")
-        return impl
-    return env_choice("QUIP_KNN_IMPL", _HOST_IMPLS, "numpy")
+    if impl is None:
+        impl = env_choice("QUIP_KNN_IMPL", _HOST_IMPLS, "numpy")
+    return _runs("knn", impl)
 
 
 def resolve_segment_impl(impl: Optional[str] = None) -> str:
     """Segment-reduction dispatch: explicit ``impl`` > ``QUIP_SEGMENT_IMPL``
     env > ``"numpy"`` (the per-segment host oracle, bit-identical to the
     interpreter's per-group reductions)."""
-    if impl is not None:
-        if impl not in _HOST_IMPLS:
-            raise ValueError(f"unknown segment impl {impl!r}")
-        return impl
-    return env_choice("QUIP_SEGMENT_IMPL", _HOST_IMPLS, "numpy")
+    if impl is None:
+        impl = env_choice("QUIP_SEGMENT_IMPL", _HOST_IMPLS, "numpy")
+    return _runs("segment", impl)
 
 
 _SEGMENT_OPS = ("count", "sum", "min", "max")
@@ -364,7 +464,7 @@ def segment_reduce(
     ``impl`` (or ``QUIP_SEGMENT_IMPL``): ``numpy`` (default; float64 host
     reductions, bit-identical to the interpreter's per-group path and the
     impl the compiled executor uses), ``ref`` (jnp/XLA segment ops), or
-    ``pallas`` (TPU kernel; interpret mode elsewhere).  The device paths
+    ``pallas`` (TPU kernel; interpret mode on the CPU).  The device paths
     compute in int32/float32, so integer results are identical while
     within int32 range and float results may differ in final-ulp
     accumulation order — they are benchmark/TPU paths, not the
@@ -389,18 +489,24 @@ def segment_reduce(
                         else (np.int64 if np.issubdtype(vals.dtype, np.integer)
                               else np.float64))
     if impl == "numpy" or len(seg) == 0:
+        KERNEL_CALLS.record("segment_reduce", "numpy")
         return _segment_numpy(vals, seg, num_segments, op)
+    KERNEL_CALLS.record("segment_reduce", impl)
     integer = np.issubdtype(vals.dtype, np.integer)
-    jv = jnp.asarray(vals, dtype=jnp.int32 if integer else jnp.float32)
-    js = jnp.asarray(seg, dtype=jnp.int32)
+    # pad rows (segment id -1: dropped) and segments to bucketed counts
+    rows = _bucket(len(seg), 512)
+    segs = _bucket(num_segments, 8)
+    jv = jnp.asarray(_pad_rows(vals.astype(np.int32 if integer
+                                           else np.float32), rows))
+    js = jnp.asarray(_pad_rows(seg.astype(np.int32), rows, -1))
     if impl == "pallas":
         out = segment_reduce_pallas(
-            jv, js, num_segments=num_segments, op=op,
-            interpret=_interpret(),
+            jv, js, num_segments=segs, op=op, interpret=_interpret(),
         )
     else:
-        out = _seg_ref_jit(jv, js, num_segments, op)
-    res = np.asarray(out).astype(np.int64 if integer else np.float64)
+        out = _seg_ref_jit(jv, js, segs, op)
+    res = np.asarray(out)[:num_segments].astype(
+        np.int64 if integer else np.float64)
     if op in ("min", "max"):
         # the device paths computed in int32/float32, so empty segments hold
         # the *compute*-dtype extreme; restamp the output-dtype identity so
@@ -447,7 +553,7 @@ def neighbor_aggregate(
 
     ``impl`` (or ``QUIP_KNN_IMPL``): ``numpy`` (default; float64 mean,
     bit-identical to the seed engine on CPU), ``ref`` (jnp/XLA, float32
-    mean), or ``pallas`` (TPU kernel; interpret mode elsewhere).  The mode
+    mean), or ``pallas`` (TPU kernel; interpret mode on the CPU).  The mode
     path dictionary-compresses on the host (``np.unique``) so the device
     kernels see dense class codes; integer results are identical across all
     three impls, float means may differ in final-ulp accumulation order.
@@ -458,15 +564,19 @@ def neighbor_aggregate(
         raise ValueError(f"neighbor_aggregate expects (b, k), got {neigh.shape}")
     if neigh.shape[0] == 0:
         return np.zeros(0, dtype=np.float64)
+    KERNEL_CALLS.record("neighbor_mode" if categorical else "neighbor_mean",
+                        impl)
     if not categorical:
         if impl == "numpy":
             return neigh.astype(np.float64).mean(axis=1)
-        vals = jnp.asarray(neigh, dtype=jnp.float32)
+        b = neigh.shape[0]
+        vals = jnp.asarray(_pad_rows(neigh.astype(np.float32),
+                                     _bucket(b, 128)))
         if impl == "pallas":
             out = neighbor_mean_pallas(vals, interpret=_interpret())
         else:
             out = _mean_ref_jit(vals)
-        return np.asarray(out, dtype=np.float64)
+        return np.asarray(out, dtype=np.float64)[:b]
     uniq, inv = np.unique(neigh, return_inverse=True)
     codes = inv.reshape(neigh.shape).astype(np.int32)
     b, k = codes.shape
@@ -482,12 +592,14 @@ def neighbor_aggregate(
         if impl == "numpy":
             parts.append(_mode_codes_numpy(sub, num_classes))
         elif impl == "pallas":
-            parts.append(np.asarray(
-                neighbor_mode_pallas(
-                    jnp.asarray(sub), num_classes=num_classes,
-                    interpret=_interpret(),
-                )
-            ))
+            # pad rows and classes to buckets: an extra class counts zero
+            # and never wins, a padded row is sliced off
+            out = neighbor_mode_pallas(
+                jnp.asarray(_pad_rows(sub, _bucket(len(sub), 128))),
+                num_classes=_bucket(num_classes, 256),
+                interpret=_interpret(),
+            )
+            parts.append(np.asarray(out)[:len(sub)])
         else:
             parts.append(np.asarray(_mode_ref_jit(jnp.asarray(sub),
                                                   num_classes)))

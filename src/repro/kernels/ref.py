@@ -6,6 +6,8 @@ These are the semantics of record: kernels are validated against these in
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -92,11 +94,13 @@ def masked_distance_ref(
     """
     q = q.astype(jnp.float32) * qm
     r = r.astype(jnp.float32) * rm
-    q2 = (q * q) @ rm.T  # sum_k qm*q^2*rm  (qm baked into q)
-    r2 = qm @ (r * r).T
-    cross = q @ r.T
+    # full f32 matmuls (a TPU's default f32 precision is one bf16 pass)
+    mm = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    q2 = mm(q * q, rm.T)  # sum_k qm*q^2*rm  (qm baked into q)
+    r2 = mm(qm, (r * r).T)
+    cross = mm(q, r.T)
     sq = q2 + r2 - 2.0 * cross
-    n_co = qm @ rm.T
+    n_co = mm(qm, rm.T)
     d = q.shape[1]
     scaled = jnp.where(n_co > 0, sq * (d / jnp.maximum(n_co, 1.0)), jnp.inf)
     return jnp.maximum(scaled, 0.0)

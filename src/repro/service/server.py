@@ -208,6 +208,7 @@ class QuipService:
         workers: int = 0,
         exec_impl: Optional[str] = None,
         compile_after_hits: int = 2,
+        segment_impl: Optional[str] = None,
         tracer=None,
         explain: Optional[bool] = None,
         ivm: Optional[bool] = None,
@@ -225,6 +226,9 @@ class QuipService:
                 f"compile_after_hits must be >= 1, got {compile_after_hits}"
             )
         self.compile_after_hits = int(compile_after_hits)
+        # grouped-aggregate segment reduction of compiled plans (None:
+        # QUIP_SEGMENT_IMPL, numpy unset); resolved when a plan lowers
+        self.segment_impl = segment_impl
         self.registry: TableRegistry = (
             tables if isinstance(tables, TableRegistry)
             else TableRegistry(tables)
@@ -365,7 +369,8 @@ class QuipService:
         # model) are deliberately NOT part of the key: answers are
         # policy-independent (see docs/serving.md "Scheduling & QoS"),
         # so an answer computed under one policy is valid under any other
-        exec_sig = (strategy, self.shared_impute, self.exec_impl) + tuple(
+        exec_sig = (strategy, self.shared_impute, self.exec_impl,
+                    self.segment_impl) + tuple(
             sorted(self._exec_kwargs.items())
         )
         return (query_signature(query, self.plan_cache.planner), exec_sig,
@@ -405,6 +410,7 @@ class QuipService:
                             use_vf=self._exec_kwargs["use_vf"],
                             minmax_opt=self._exec_kwargs["minmax_opt"],
                             join_impl=self._exec_kwargs["join_impl"],
+                            segment_impl=self.segment_impl,
                         )
                     except CompileFallback as e:
                         # cache the fallback too — this signature can

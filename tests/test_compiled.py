@@ -297,6 +297,23 @@ def test_service_promotes_on_kth_hit_and_invalidates_on_mutation():
     assert impls == ["interp", "interp", "compiled", "compiled"]
 
 
+def test_service_segment_impl_reaches_compiled_plans():
+    """QuipService(segment_impl=) picks the grouped reduction of the plans
+    it compiles, and answers stay those of the interpreter."""
+    tables, _clean, truth = _instance()
+    q = _query(Aggregate("count", "R0.v", group_by="R1.v"))
+    _reg, svc_c = _service(tables, truth, exec_impl="compiled",
+                           compile_after_hits=1, segment_impl="ref")
+    _reg, svc_i = _service(tables, truth)
+    before = kops.KERNEL_CALLS.snapshot().get("segment_reduce/ref", 0)
+    for _ in range(2):
+        assert (_canon(svc_c.answers(svc_c.submit(q)))
+                == _canon(svc_i.answers(svc_i.submit(q))))
+    after = kops.KERNEL_CALLS.snapshot().get("segment_reduce/ref", 0)
+    assert svc_c.summary()["compiled_hits"] == 1
+    assert after > before
+
+
 def test_service_caches_fallback_for_ineligible_strategy():
     tables, _clean, truth = _instance()
     q = _query()

@@ -1,6 +1,7 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True on CPU) vs the
-pure-jnp oracles in repro.kernels.ref, plus hypothesis property tests for
-the bloom filter's no-false-negative invariant."""
+pure-jnp oracles in repro.kernels.ref, hypothesis property tests for the
+bloom filter's no-false-negative invariant, and the per-backend dispatch
+table (``ops.PALLAS_RUNS``) with its call tally."""
 
 from __future__ import annotations
 
@@ -15,6 +16,44 @@ from repro.kernels import ref as kref
 from repro.kernels.bloom_probe import bloom_probe_pallas
 from repro.kernels.hashing import fold64, hash_positions_np
 from repro.kernels.knn_distance import masked_distance_pallas
+
+
+# --------------------------------------------------------------------------- #
+# dispatch: the backend table and the call tally
+# --------------------------------------------------------------------------- #
+@pytest.mark.parametrize("backend,join,interpret", [
+    ("cpu", "pallas", True), ("tpu", "ref", False),
+])
+def test_pallas_runs_per_backend(monkeypatch, backend, join, interpret):
+    monkeypatch.setattr(kops.jax, "default_backend", lambda: backend)
+    for var in ("QUIP_BLOOM_IMPL", "QUIP_DIST_IMPL", "QUIP_JOIN_IMPL"):
+        monkeypatch.delenv(var, raising=False)
+    assert kops.default_impl() == ("pallas" if backend == "tpu" else "ref")
+    assert kops.resolve_join_impl("pallas") == join
+    assert kops.resolve_segment_impl("pallas") == "pallas"
+    assert kops._interpret() is interpret
+    assert kops.KERNEL_CALLS.key("bloom_probe", "pallas") == (
+        "bloom_probe/pallas-interpret" if interpret else "bloom_probe/pallas")
+
+
+def test_unknown_backend_raises(monkeypatch):
+    monkeypatch.setattr(kops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="no kernel dispatch"):
+        kops.default_impl()
+    with pytest.raises(RuntimeError, match="no kernel dispatch"):
+        kops.resolve_bloom_impl("pallas")
+
+
+def test_kernel_calls_record_the_impl_that_ran():
+    before = kops.KERNEL_CALLS.snapshot()
+    bits = np.zeros((1 << 14) // 32, dtype=np.uint32)
+    kops.bloom_probe(bits, fold64(np.arange(5)), num_hashes=2, log2m=14,
+                     impl="numpy")
+    kops.bloom_probe(bits, fold64(np.arange(5)), num_hashes=2, log2m=14,
+                     impl="pallas")
+    after = kops.KERNEL_CALLS.snapshot()
+    for key in ("bloom_probe/numpy", "bloom_probe/pallas-interpret"):
+        assert after[key] == before.get(key, 0) + 1
 
 
 # --------------------------------------------------------------------------- #
