@@ -588,75 +588,85 @@ class QuipExecutor:
                 yield self._normalize(node, padded)
 
         # ---- probe (left) side: stream --------------------------------- #
-        first = True
         for morsel in self._stream_subtree(node.children[0]):
-            morsel = self._prepare_join_side(node, js, "L", l_attr, morsel)
-            js.append_snapshot("L", morsel)
-            if morsel.num_rows == 0:
-                continue
-            p_present = morsel.is_present(l_attr)
-            self.blooms[l_attr].insert(morsel.values(l_attr)[p_present])
-            p_missing_rows = np.nonzero(morsel.is_missing(l_attr))[0]
-            if len(p_missing_rows):
-                js.record_deferred(
-                    "L", morsel.tids[table_of(l_attr)][p_missing_rows]
-                )
-
-            t0 = time.perf_counter()
-            probe_keys = np.where(
-                p_present, morsel.values(l_attr), np.int64(-(2 ** 61))
-            ).astype(np.int64)
-            with (tr.span("kernel:multi_match", cat="kernel",
-                          node=node.node_id, impl=self.join_impl,
-                          build=len(b_keys), probe=len(probe_keys))
+            with (tr.span("op:join_probe", node=node.node_id,
+                          rows=morsel.num_rows)
                   if tr.enabled else NULL_SPAN):
-                p_idx, b_idx = multi_match(
-                    b_keys, probe_keys, impl=self.join_impl
-                )
-            dt = time.perf_counter() - t0
-            self.counters.join_tests += int(p_present.sum())
-            self.stats.record_join(
-                node.node_id,
-                tests=max(int(p_present.sum()), 1),
-                tuples=max(int(p_present.sum()), 1),
-                seconds=dt,
-            )
-            matched = np.zeros(morsel.num_rows, dtype=bool)
-            if len(p_idx):
-                matched[p_idx] = True
-            # |out| / (|L|·|R|) selectivity over known rows
-            denom = max(int(p_present.sum()) * max(len(b_keys), 1), 1)
-            self.stats.record_selectivity(node.node_id, len(p_idx), denom)
-
-            pieces = []
-            if len(p_idx):
-                joined = morsel.take(p_idx).hstack(build.take(b_idx))
-                pieces.append(joined)
-            # preserved: missing (deferred) or absent key rows → pad right
-            keep_outer = ~p_present
-            if keep_outer.any():
-                l_side = morsel.filter(keep_outer)
-                r_pad = self._pad_for_tables(r_tabs, l_side.num_rows)
-                pieces.append(l_side.hstack(r_pad))
-            # unmatched present-key rows are dropped from the stream (their
-            # snapshot copies still serve L1⋈R2 triggers)
-            unmatched = morsel.filter(p_present & ~matched)
-            if unmatched.num_rows:
-                self.on_rows_dropped(unmatched, None)
-            if pieces:
-                out = concat_relations(
-                    [self._normalize(node, p) for p in pieces]
-                )
-                out = apply_dynamic_preds(self, node, out)
-                if out.num_rows:
-                    self.counters.temp_tuples += out.num_rows
-                    yield out
-            first = False
+                out = self._probe(node, js, l_attr, r_tabs, b_keys, build,
+                                  morsel)
+            if out is not None and out.num_rows:
+                self.counters.temp_tuples += out.num_rows
+                yield out
 
         self.consumed[l_attr] = True
         js.sides["L"].consumed = True
         js.finalize_deferred()
         self.maybe_complete_bloom(l_attr)
+
+    def _probe(self, node: JoinNode, js: JoinState, l_attr: str,
+               r_tabs: Sequence[str], b_keys: np.ndarray,
+               build: MaskedRelation, morsel: MaskedRelation
+               ) -> Optional[MaskedRelation]:
+        """One probe-side morsel of ⋈̂: prepare, match against the build
+        keys, and assemble the joined and outer-preserved rows."""
+        tr = self.tracer
+        morsel = self._prepare_join_side(node, js, "L", l_attr, morsel)
+        js.append_snapshot("L", morsel)
+        if morsel.num_rows == 0:
+            return None
+        p_present = morsel.is_present(l_attr)
+        self.blooms[l_attr].insert(morsel.values(l_attr)[p_present])
+        p_missing_rows = np.nonzero(morsel.is_missing(l_attr))[0]
+        if len(p_missing_rows):
+            js.record_deferred(
+                "L", morsel.tids[table_of(l_attr)][p_missing_rows]
+            )
+
+        t0 = time.perf_counter()
+        probe_keys = np.where(
+            p_present, morsel.values(l_attr), np.int64(-(2 ** 61))
+        ).astype(np.int64)
+        with (tr.span("kernel:multi_match", cat="kernel",
+                      node=node.node_id, impl=self.join_impl,
+                      build=len(b_keys), probe=len(probe_keys))
+              if tr.enabled else NULL_SPAN):
+            p_idx, b_idx = multi_match(
+                b_keys, probe_keys, impl=self.join_impl
+            )
+        dt = time.perf_counter() - t0
+        self.counters.join_tests += int(p_present.sum())
+        self.stats.record_join(
+            node.node_id,
+            tests=max(int(p_present.sum()), 1),
+            tuples=max(int(p_present.sum()), 1),
+            seconds=dt,
+        )
+        matched = np.zeros(morsel.num_rows, dtype=bool)
+        if len(p_idx):
+            matched[p_idx] = True
+        # |out| / (|L|·|R|) selectivity over known rows
+        denom = max(int(p_present.sum()) * max(len(b_keys), 1), 1)
+        self.stats.record_selectivity(node.node_id, len(p_idx), denom)
+
+        pieces = []
+        if len(p_idx):
+            joined = morsel.take(p_idx).hstack(build.take(b_idx))
+            pieces.append(joined)
+        # preserved: missing (deferred) or absent key rows → pad right
+        keep_outer = ~p_present
+        if keep_outer.any():
+            l_side = morsel.filter(keep_outer)
+            r_pad = self._pad_for_tables(r_tabs, l_side.num_rows)
+            pieces.append(l_side.hstack(r_pad))
+        # unmatched present-key rows are dropped from the stream (their
+        # snapshot copies still serve L1⋈R2 triggers)
+        unmatched = morsel.filter(p_present & ~matched)
+        if unmatched.num_rows:
+            self.on_rows_dropped(unmatched, None)
+        if not pieces:
+            return None
+        out = concat_relations([self._normalize(node, p) for p in pieces])
+        return apply_dynamic_preds(self, node, out)
 
     def _prepare_join_side(self, node: JoinNode, js: JoinState, s: str,
                            attr: str, rel: MaskedRelation) -> MaskedRelation:
@@ -961,17 +971,22 @@ class QuipExecutor:
             yield
 
         t0 = time.perf_counter()
-        rel = (
-            concat_relations(chunks)
-            if chunks
-            else self._pad_for_tables(self.query.tables, 0)
-        )
-        aux = None
-        if agg is not None:
-            aux = agg_aux_of(rel, agg)
-            rel = _aggregate(rel, agg)
-        elif proj is not None:
-            rel = rel.project(list(proj))
+        tr = self.tracer
+        with (tr.span("op:finalize", agg=agg is not None)
+              if tr.enabled else NULL_SPAN) as sp:
+            rel = (
+                concat_relations(chunks)
+                if chunks
+                else self._pad_for_tables(self.query.tables, 0)
+            )
+            if tr.enabled:
+                sp.set(rows=rel.num_rows)
+            aux = None
+            if agg is not None:
+                aux = agg_aux_of(rel, agg)
+                rel = _aggregate(rel, agg)
+            elif proj is not None:
+                rel = rel.project(list(proj))
         active += time.perf_counter() - t0
         self.counters.wall_seconds = active + self.engine.simulated_seconds
         self.result = ExecutionResult(rel, self.counters, self.stats,

@@ -58,6 +58,9 @@ class Imputer:
     blocking: bool = False
     cost_per_value: float = 0.0  # simulated seconds per imputed value
     train_cost: float = 0.0  # simulated seconds, charged once (blocking)
+    # the span tracer of the service that last handed the model out
+    # (``ImputationService._model_for``); models may span their device calls
+    tracer = NULL_TRACER
 
     def fit(self, table: MaskedRelation) -> None:  # pragma: no cover
         pass
@@ -361,9 +364,15 @@ class ImputationService:
 
     # ------------------------------------------------------------------ #
     def _model_for(self, table: str, attr: str) -> Imputer:
-        model, train_wall = self.store.model_for(
-            table, attr, self._default, self._per_attr
-        )
+        tr = self.tracer
+        with (tr.span("impute:fit", cat="impute", table=table, attr=attr)
+              if tr.enabled else NULL_SPAN) as sp:
+            model, train_wall = self.store.model_for(
+                table, attr, self._default, self._per_attr
+            )
+            if tr.enabled:
+                sp.set(fitted=train_wall is not None)
+        model.tracer = tr
         if train_wall is not None and model.blocking:
             with self._tel_lock:
                 self.simulated_seconds += model.train_cost

@@ -20,6 +20,7 @@ import numpy as np
 from repro.core.relation import MaskedRelation
 from repro.imputers.base import Imputer
 from repro.kernels import ops as kops
+from repro.obs.trace import NULL_SPAN
 
 __all__ = ["KnnImputer"]
 
@@ -72,14 +73,17 @@ class KnnImputer(Imputer):
         keep[ai] = False
         out = np.zeros(len(tids), dtype=np.float64)
         is_int = not np.issubdtype(table.cols[attr].dtype, np.floating)
+        tr = self.tracer
         for lo in range(0, len(tids), self.batch):
             idx = tids[lo : lo + self.batch]
             q, qm = self._feat[idx][:, keep], self._mask[idx][:, keep]
-            _d, nn = kops.masked_knn(
-                q, qm, r[:, keep], rm[:, keep],
-                k=min(self.k, r.shape[0]), impl=self.impl,
-            )
-            nn = np.asarray(nn)
+            with (tr.span("knn:call", cat="kernel", attr=attr, nq=len(idx),
+                          nr=r.shape[0], d=int(keep.sum()))
+                  if tr.enabled else NULL_SPAN) as sp:
+                _d, nn = kops.masked_knn(
+                    q, qm, r[:, keep], rm[:, keep],
+                    k=min(self.k, r.shape[0]), impl=self.impl, span=sp,
+                )
             neigh = tgt[nn]  # (b, k) raw target values
             # vectorized neighbour aggregation: bincount-argmax mode for
             # dictionary-coded categoricals, mean for floats (no per-row

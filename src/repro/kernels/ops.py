@@ -45,6 +45,7 @@ from repro.kernels.hashing import MULTIPLIERS, OFFSETS, fold64
 from repro.kernels.knn_distance import masked_distance_pallas
 from repro.kernels.neighbor_agg import neighbor_mean_pallas, neighbor_mode_pallas
 from repro.kernels.segment_ops import segment_reduce_pallas
+from repro.obs.trace import NULL_SPAN
 
 __all__ = [
     "KERNEL_CALLS",
@@ -374,14 +375,26 @@ def masked_knn(
     k: int,
     *,
     impl: Optional[str] = None,
+    span=NULL_SPAN,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Top-k smallest masked distances per query row.  Query rows are
     padded to a power-of-two count; a padded row observes nothing, so its
-    distances are +inf, and it is sliced off."""
+    distances are +inf, and it is sliced off.
+
+    ``span``, an open tracing span, is given ``nq_padded`` and
+    ``h2d_bytes``: the bytes of the host arrays the call hands to a device
+    program (the distance program's inputs, or the host-computed distances
+    that top-k reads)."""
     nq = q.shape[0]
     rows = _bucket(nq, 128)
-    dmat = masked_distance(_pad_rows(np.asarray(q), rows),
-                           _pad_rows(np.asarray(qm), rows), r, rm, impl=impl)
+    qp = _pad_rows(np.asarray(q), rows)
+    qmp = _pad_rows(np.asarray(qm), rows)
+    dmat = masked_distance(qp, qmp, r, rm, impl=impl)
+    if span is not NULL_SPAN:
+        sent = (dmat,) if isinstance(dmat, np.ndarray) else (qp, qmp, r, rm)
+        span.set(nq_padded=rows,
+                 h2d_bytes=sum(x.nbytes for x in sent
+                               if isinstance(x, np.ndarray)))
     dist, idx = _top_k_jit(jnp.asarray(dmat), k)
     return np.asarray(dist)[:nq], np.asarray(idx)[:nq]
 

@@ -25,14 +25,30 @@ Design constraints, in order:
 Per-query attribution: a span created with ``ticket=`` stamps it; nested
 spans without one inherit the nearest enclosing span's ticket on the same
 thread.  ``chrome_trace(ticket=...)`` exports one query's tree.
+
+Profiler mirror: an enabled tracer's thread-local spans also enter a
+``jax.profiler.TraceAnnotation`` named ``quip:<span name>``, so that a JAX
+profile holds the program's spans on the device events' clock.  Cross-thread
+:meth:`Tracer.begin`/:meth:`Tracer.end` spans and instants are not mirrored:
+a profiler annotation opens and closes on one thread.
+
+Compiles: a wall-clock tracer records every XLA compile the process makes
+while it is alive as a ``jax:compile`` span (``fun_name``, ``secs``, and
+``cache_load`` when the executable came from the persistent compile cache),
+nested under the span open on the compiling thread.  The unit clock leaves
+them out: what compiles depends on the process's caches, not on the query.
 """
 
 from __future__ import annotations
 
 import threading
 import time
+import weakref
 from collections import Counter
 from typing import Dict, List, Optional
+
+from jax import monitoring
+from jax.profiler import TraceAnnotation
 
 from repro.analysis.lockcheck import make_lock
 from repro.core.env import env_choice, env_flag
@@ -40,6 +56,7 @@ from repro.core.env import env_choice, env_flag
 __all__ = [
     "NULL_SPAN",
     "NULL_TRACER",
+    "PROFILER_PREFIX",
     "Span",
     "Tracer",
     "TRACE_CLOCKS",
@@ -47,6 +64,9 @@ __all__ = [
 ]
 
 TRACE_CLOCKS = ("wall", "unit")
+
+#: prefix of the program's spans in a JAX profile
+PROFILER_PREFIX = "quip:"
 
 
 class _NullSpan:
@@ -94,19 +114,22 @@ class Span:
 
 
 class _LiveSpan:
-    """Context-manager handle for one open span on the current thread."""
+    """Context-manager handle for one open span on the current thread,
+    mirrored into the JAX profiler while it is open."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_mirror")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self._span = span
+        self._mirror = TraceAnnotation(PROFILER_PREFIX + span.name)
 
     def set(self, **attrs) -> "_LiveSpan":
         self._span.args.update(attrs)
         return self
 
     def __enter__(self) -> "_LiveSpan":
+        self._mirror.__enter__()
         self._tracer._push(self._span)
         return self
 
@@ -114,7 +137,47 @@ class _LiveSpan:
         if exc_type is not None:
             self._span.args.setdefault("error", exc_type.__name__)
         self._tracer._pop(self._span)
+        self._mirror.__exit__(exc_type, exc, tb)
         return False
+
+
+# --------------------------------------------------------------------------- #
+# compile events (jax.monitoring), fanned out to the live wall-clock tracers
+# --------------------------------------------------------------------------- #
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_compile_lock = make_lock("trace._compile_lock")
+_compile_watchers: "weakref.WeakSet[Tracer]" = weakref.WeakSet()  # guarded-by: _compile_lock
+_compile_listening = False  # guarded-by: _compile_lock
+_compile_tls = threading.local()
+
+
+def _on_event(event: str, **_kw) -> None:
+    # JAX reports a persistent-cache hit on the compiling thread just before
+    # the compile event that wraps it
+    if event == _CACHE_HIT_EVENT:
+        _compile_tls.cache_load = True
+
+
+def _on_duration(event: str, secs: float, **kw) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    cache_load = getattr(_compile_tls, "cache_load", False)
+    _compile_tls.cache_load = False
+    with _compile_lock:
+        watchers = list(_compile_watchers)
+    for tracer in watchers:
+        tracer._record_compile(str(kw.get("fun_name", "")), secs, cache_load)
+
+
+def _watch_compiles(tracer: "Tracer") -> None:
+    global _compile_listening
+    with _compile_lock:
+        if not _compile_listening:
+            monitoring.register_event_listener(_on_event)
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            _compile_listening = True
+        _compile_watchers.add(tracer)
 
 
 class Tracer:
@@ -122,7 +185,8 @@ class Tracer:
 
     ``enabled=False`` (the default of :func:`resolve_tracer` without
     ``QUIP_TRACE``) makes every recording call a no-op returning
-    :data:`NULL_SPAN`."""
+    :data:`NULL_SPAN`.  Under the wall clock it also records the
+    process's XLA compiles as ``jax:compile`` spans."""
 
     def __init__(self, enabled: bool = True, clock: str = "wall"):
         if clock not in TRACE_CLOCKS:
@@ -137,6 +201,8 @@ class Tracer:
         self._tick = 0  # guarded-by: _lock
         self._origin = time.perf_counter()
         self._tls = threading.local()
+        if self.enabled and clock == "wall":
+            _watch_compiles(self)
 
     # -- clock / ids ------------------------------------------------------#
     def now(self) -> float:
@@ -211,6 +277,21 @@ class Tracer:
                     threading.current_thread().name, self.now(), args,
                     ph="i")
         span.t1 = span.t0
+        with self._lock:
+            self._records.append(span)
+
+    def _record_compile(self, fun_name: str, secs: float,
+                        cache_load: bool) -> None:
+        """One compile that just ended on this thread: a complete span of
+        its ``secs``, under the span open here (if any)."""
+        top = self._parent()
+        t1 = self.now()
+        span = Span(self._new_id(), top.span_id if top else None,
+                    "jax:compile", "compile", top.ticket if top else None,
+                    threading.current_thread().name, t1 - secs,
+                    {"fun_name": fun_name, "secs": secs,
+                     "cache_load": cache_load})
+        span.t1 = t1
         with self._lock:
             self._records.append(span)
 

@@ -56,6 +56,7 @@ from repro.core.relation import MaskedRelation
 from repro.core.stats import ExecutionCounters, QueryRecord, ServingStats
 from repro.imputers.base import ImputationService, Imputer
 from repro.obs import (
+    NULL_SPAN,
     ProvenanceRecorder,
     build_service_metrics,
     render_explain,
@@ -431,7 +432,11 @@ class QuipService:
             # while the session waits in the admission queue.
             snaps = {t: self.tables[t] for t in query.tables}
             key = self._result_key(query, strategy)
-        tables = {t: rel.copy() for t, rel in snaps.items()}
+        tr = self.tracer
+        with (tr.span("session:snapshot", cat="sched", tables=len(snaps),
+                      rows=sum(rel.num_rows for rel in snaps.values()))
+              if tr.enabled else NULL_SPAN):
+            tables = {t: rel.copy() for t, rel in snaps.items()}
         engine = self._make_engine(tables)
         if fallback is not None:
             engine.counters.compile_fallbacks += 1

@@ -615,3 +615,172 @@ def test_traced_worker_pool_counts_reconcile():
             == record.counters.imputations
     assert GroundTruthImputer is not None
     svc.close()
+
+
+# --------------------------------------------------------------------------- #
+# table snapshots, imputer fits, k-NN calls, finalize; the profiler mirror;
+# compile spans
+# --------------------------------------------------------------------------- #
+#: each new span and the span it nests in
+_NESTED_IN = {
+    "session:snapshot": "session_setup",
+    "impute:fit": "impute_flush",
+    "knn:call": "impute_flush",
+    "op:finalize": "morsel_step",
+}
+
+
+def _knn_service(tracer):
+    from repro.imputers import KnnImputer
+
+    tables, _clean, _truth = _instance()
+    return QuipService(tables, lambda: KnnImputer(k=3), strategy="lazy",
+                       max_inflight=1, morsel_rows=8, tracer=tracer)
+
+
+def _knn_answers(tracer):
+    svc = _knn_service(tracer)
+    tickets = [svc.submit(q) for q in WORKLOAD]
+    answers = [Counter(svc.answers(t)) for t in tickets]
+    imputations = svc.serving.total_counters().imputations
+    svc.close()
+    return answers, imputations
+
+
+def test_new_spans_nest_under_their_parents():
+    tracer = Tracer(**UNIT)
+    svc = _knn_service(tracer)
+    tickets = [svc.submit(q) for q in WORKLOAD]
+    svc.run_until_idle()
+    spans = tracer.spans()
+    name_of = {s.span_id: s.name for s in spans}
+    for ticket in tickets:
+        counts = tracer.span_counts(ticket)
+        for child, parent in _NESTED_IN.items():
+            assert counts.get(child, 0) >= 1, (ticket, child)
+    for s in spans:
+        if s.name in _NESTED_IN:
+            assert name_of[s.parent_id] == _NESTED_IN[s.name], s.name
+    # the probe side of a join runs under whatever pulls the join's stream
+    probes = tracer.spans(name="op:join_probe")
+    assert probes and all(name_of[s.parent_id] in ("morsel_step",
+                                                   "op:join_build")
+                          for s in probes)
+    fits = tracer.spans(name="impute:fit")
+    assert {s.args["fitted"] for s in fits} == {True, False}
+    assert all(s.args["table"] and s.args["attr"] for s in fits)
+    for s in tracer.spans(name="knn:call"):
+        a = s.args
+        assert a["nq"] <= a["nq_padded"] and a["nq_padded"] % 128 == 0
+        # on the CPU the distances run in XLA: q, qm at the padded rows and
+        # the reference rows r, rm, all float32
+        assert a["h2d_bytes"] == 4 * a["d"] * 2 * (a["nq_padded"] + a["nr"])
+    for s in tracer.spans(name="session:snapshot"):
+        assert s.args["tables"] == 2 and s.args["rows"] > 0
+    for s in tracer.spans(name="op:finalize"):
+        assert s.args["agg"] is False and s.args["rows"] >= 0
+    svc.close()
+
+
+def test_knn_traced_equals_untraced():
+    assert _knn_answers(Tracer(**UNIT)) == _knn_answers(None)
+
+
+@pytest.mark.parametrize("impl,sent", [
+    # q and qm padded from 3 to 128 rows, r and rm at 10 rows: 4 features
+    # of float32 each
+    ("ref", 4 * 4 * (128 + 128 + 10 + 10)),
+    # distances on the host: only the (128, 10) matrix goes to top-k
+    ("numpy", 4 * 128 * 10),
+])
+def test_masked_knn_h2d_bytes_hand_counted(impl, sent):
+    from repro.kernels import ops as kops
+
+    rng = np.random.default_rng(0)
+    q = rng.random((3, 4), dtype=np.float32)
+    r = rng.random((10, 4), dtype=np.float32)
+    tr = Tracer(**UNIT)
+    with tr.span("knn:call") as sp:
+        _d, nn = kops.masked_knn(q, np.ones_like(q), r, np.ones_like(r), 2,
+                                 impl=impl, span=sp)
+    assert nn.shape == (3, 2)
+    assert tr.spans()[0].args == {"nq_padded": 128, "h2d_bytes": sent}
+
+
+class _SpanRefusingTracer(Tracer):
+    """A disabled tracer whose recording calls fail: a site that skips the
+    ``if tracer.enabled`` guard builds its span, and the test sees it."""
+
+    def span(self, *a, **kw):
+        raise AssertionError(f"unguarded span {a}")
+
+
+def test_disabled_tracer_records_no_knn_or_fit_spans():
+    tracer = _SpanRefusingTracer(enabled=False)
+    answers, imputations = _knn_answers(tracer)
+    assert imputations > 0 and tracer.spans() == []
+    assert (answers, imputations) == _knn_answers(None)
+
+
+def _profiled_events(tmp_path, tracer):
+    import jax
+
+    from repro.obs.trace import PROFILER_PREFIX
+
+    svc = _knn_service(tracer)
+    svc.answers(svc.submit(WORKLOAD[0]))  # compile outside the profile
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        svc.answers(svc.submit(WORKLOAD[1]))
+    finally:
+        jax.profiler.stop_trace()
+        svc.close()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    return [(plane.name, line.name, ev.name, ev.start_ns,
+             ev.start_ns + ev.duration_ns)
+            for plane in data.planes for line in plane.lines
+            for ev in line.events if ev.name.startswith(PROFILER_PREFIX)]
+
+
+def test_profiler_trace_holds_the_program_spans(tmp_path):
+    events = _profiled_events(tmp_path / "on", Tracer(enabled=True))
+    names = Counter(e[2] for e in events)
+    for name in ("session_setup", "impute_flush", "knn:call"):
+        assert names["quip:" + name] >= 1, names
+    assert all(not plane.startswith("/device:") or plane.startswith(
+        "/device:CPU") for plane, *_ in events)
+    flushes = [e for e in events if e[2] == "quip:impute_flush"]
+    for plane, line, _n, s, t in (e for e in events
+                                  if e[2] == "quip:knn:call"):
+        assert any(f[0] == plane and f[1] == line and f[3] <= s and t <= f[4]
+                   for f in flushes)
+    assert _profiled_events(tmp_path / "off", Tracer(enabled=False)) == []
+
+
+def test_compile_spans_nest_under_the_compiling_span():
+    import jax
+    from jax import monitoring
+
+    tr = Tracer(enabled=True)
+    with tr.span("outer", ticket=4):
+        jax.jit(lambda x: x * 3 + 1)(np.arange(5.0))
+        # a persistent-cache load reports its hit before the compile event
+        monitoring.record_event("/jax/compilation_cache/cache_hits")
+        monitoring.record_event_duration_secs(
+            "/jax/core/compile/backend_compile_duration", 0.25,
+            fun_name="loaded")
+    (outer,) = tr.spans(name="outer")
+    compiles = tr.spans(name="jax:compile")
+    loaded = [s for s in compiles if s.args["fun_name"] == "loaded"]
+    assert len(compiles) == 2 and len(loaded) == 1
+    assert loaded[0].args == {"fun_name": "loaded", "secs": 0.25,
+                              "cache_load": True}
+    for s in compiles:
+        assert s.parent_id == outer.span_id and s.ticket == 4
+        assert s.t1 - s.t0 == pytest.approx(s.args["secs"])
+    # the unit clock's structure does not depend on the process's caches
+    unit = Tracer(**UNIT)
+    with unit.span("outer"):
+        jax.jit(lambda x: x * 5 - 2)(np.arange(5.0))
+    assert unit.span_counts() == {"outer": 1}
