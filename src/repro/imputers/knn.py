@@ -9,12 +9,17 @@ the masked distance is the Mosaic-compiled Pallas kernel on TPU and the jnp
 device.  Neighbour aggregation (mean / categorical mode) is the vectorized
 ``kernels.ops.neighbor_aggregate`` op, dispatched with ``QUIP_KNN_IMPL``
 (numpy by default | ref | pallas).
+
+Each attribute's reference rows are selected once per fit, on its first
+``impute_attr``, and kept on the device: every query batch after that sends
+only its own rows.  ``fit`` drops them, so a refit never reads stale rows.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Dict, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.relation import MaskedRelation
@@ -42,8 +47,12 @@ class KnnImputer(Imputer):
         self._mean = None
         self._std = None
         self._cols = None
+        # per attribute, its reference (r, rm, tgt) as of the last fit;
+        # callers hold the store's (table, attr) flush lock around
+        # ``fit`` and ``impute_attr``
+        self._refs: Dict[str, Tuple] = {}  # guarded-by: flush_lock
 
-    def fit(self, table: MaskedRelation) -> None:
+    def fit(self, table: MaskedRelation) -> None:  # requires: flush_lock
         cols = table.column_names()
         n = table.num_rows
         feat = np.zeros((n, len(cols)), dtype=np.float32)
@@ -61,16 +70,32 @@ class KnnImputer(Imputer):
         self._mask = mask
         self._mean, self._std = mean, std
         self._cols = cols
+        self._refs = {}
+
+    def _reference(self, table: MaskedRelation, attr: str, keep: np.ndarray,
+                   span) -> Tuple:  # requires: flush_lock
+        """``attr``'s reference ``(r, rm, tgt)``: the rows that observe
+        ``attr``, their ``keep`` features and masks, and their values of
+        ``attr``.  Made once per fit and kept; ``r`` and ``rm`` go to the
+        device (uncommitted, so the programs compiled for host arrays of
+        the same shape serve them) unless the distances run on the host,
+        and ``span`` counts the upload."""
+        ref = self._refs.get(attr)
+        if ref is not None:
+            return ref
+        rows = self._mask[:, self._cols.index(attr)].nonzero()[0]
+        r, rm = self._feat[rows][:, keep], self._mask[rows][:, keep]
+        if kops.resolve_dist_impl(self.impl) != "numpy":
+            span.add(h2d_bytes=r.nbytes + rm.nbytes)
+            r, rm = jax.device_put(r), jax.device_put(rm)
+        ref = self._refs[attr] = (r, rm, table.values(attr)[rows])
+        return ref
 
     def impute_attr(self, table: MaskedRelation, attr: str, tids: np.ndarray
-                    ) -> np.ndarray:
-        ai = self._cols.index(attr)
-        ref_rows = self._mask[:, ai] > 0  # neighbours must observe attr
-        r, rm = self._feat[ref_rows], self._mask[ref_rows]
-        tgt = table.values(attr)[ref_rows.nonzero()[0]]  # aligned targets
+                    ) -> np.ndarray:  # requires: flush_lock
         # exclude attr itself from the distance features
         keep = np.ones(self._feat.shape[1], dtype=bool)
-        keep[ai] = False
+        keep[self._cols.index(attr)] = False
         out = np.zeros(len(tids), dtype=np.float64)
         is_int = not np.issubdtype(table.cols[attr].dtype, np.floating)
         tr = self.tracer
@@ -78,10 +103,13 @@ class KnnImputer(Imputer):
             idx = tids[lo : lo + self.batch]
             q, qm = self._feat[idx][:, keep], self._mask[idx][:, keep]
             with (tr.span("knn:call", cat="kernel", attr=attr, nq=len(idx),
-                          nr=r.shape[0], d=int(keep.sum()))
+                          d=int(keep.sum()),
+                          ref_resident=attr in self._refs)
                   if tr.enabled else NULL_SPAN) as sp:
+                r, rm, tgt = self._reference(table, attr, keep, sp)
+                sp.set(nr=r.shape[0])
                 _d, nn = kops.masked_knn(
-                    q, qm, r[:, keep], rm[:, keep],
+                    q, qm, r, rm,
                     k=min(self.k, r.shape[0]), impl=self.impl, span=sp,
                 )
             neigh = tgt[nn]  # (b, k) raw target values

@@ -381,10 +381,11 @@ def masked_knn(
     padded to a power-of-two count; a padded row observes nothing, so its
     distances are +inf, and it is sliced off.
 
-    ``span``, an open tracing span, is given ``nq_padded`` and
-    ``h2d_bytes``: the bytes of the host arrays the call hands to a device
+    ``span``, an open tracing span, is given ``nq_padded`` and adds to
+    ``h2d_bytes`` the bytes of the host arrays the call hands to a device
     program (the distance program's inputs, or the host-computed distances
-    that top-k reads)."""
+    that top-k reads).  Reference rows ``r``, ``rm`` already on the device
+    are read in place and not counted."""
     nq = q.shape[0]
     rows = _bucket(nq, 128)
     qp = _pad_rows(np.asarray(q), rows)
@@ -392,9 +393,9 @@ def masked_knn(
     dmat = masked_distance(qp, qmp, r, rm, impl=impl)
     if span is not NULL_SPAN:
         sent = (dmat,) if isinstance(dmat, np.ndarray) else (qp, qmp, r, rm)
-        span.set(nq_padded=rows,
-                 h2d_bytes=sum(x.nbytes for x in sent
-                               if isinstance(x, np.ndarray)))
+        span.set(nq_padded=rows).add(
+            h2d_bytes=sum(x.nbytes for x in sent
+                          if isinstance(x, np.ndarray)))
     dist, idx = _top_k_jit(jnp.asarray(dmat), k)
     return np.asarray(dist)[:nq], np.asarray(idx)[:nq]
 

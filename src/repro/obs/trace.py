@@ -70,7 +70,7 @@ PROFILER_PREFIX = "quip:"
 
 
 class _NullSpan:
-    """The shared no-op span: context manager + ``set`` sink.
+    """The shared no-op span: context manager + ``set``/``add`` sink.
 
     A singleton (:data:`NULL_SPAN`) so the disabled path allocates
     nothing — every ``with tracer.span(...)`` site reuses this object."""
@@ -84,6 +84,9 @@ class _NullSpan:
         return False
 
     def set(self, **attrs) -> "_NullSpan":
+        return self
+
+    def add(self, **counts) -> "_NullSpan":
         return self
 
 
@@ -126,6 +129,13 @@ class _LiveSpan:
 
     def set(self, **attrs) -> "_LiveSpan":
         self._span.args.update(attrs)
+        return self
+
+    def add(self, **counts) -> "_LiveSpan":
+        """Add to numeric attributes, from 0 where unset."""
+        args = self._span.args
+        for key, n in counts.items():
+            args[key] = args.get(key, 0) + n
         return self
 
     def __enter__(self) -> "_LiveSpan":

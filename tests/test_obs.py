@@ -78,14 +78,15 @@ def test_disabled_tracer_is_allocation_free():
     tr.instant("evt")
     with tr.span("x") as sp:
         assert sp.set(a=1) is sp
+        assert sp.add(b=2) is sp
     assert tr.spans() == []
 
 
 def test_unit_clock_nesting_and_ticket_inheritance():
     tr = Tracer(**UNIT)
     with tr.span("outer", ticket=5):
-        with tr.span("inner") as sp:
-            sp.set(rows=3)
+        with tr.span("inner", bytes=1) as sp:
+            sp.set(rows=3).add(bytes=4, calls=1).add(calls=1)
         tr.instant("evt")
     spans = tr.spans(ticket=5)
     by_name = {s.name: s for s in spans}
@@ -94,7 +95,8 @@ def test_unit_clock_nesting_and_ticket_inheritance():
     assert by_name["inner"].parent_id == by_name["outer"].span_id
     assert by_name["evt"].parent_id == by_name["outer"].span_id
     assert all(s.ticket == 5 for s in spans)
-    assert by_name["inner"].args == {"rows": 3}
+    # ``add`` sums into an attribute, from 0 where it was unset
+    assert by_name["inner"].args == {"rows": 3, "bytes": 5, "calls": 2}
     assert tr.span_tree(5) == [
         {"name": "outer", "children": [
             {"name": "inner", "children": []},
@@ -669,12 +671,18 @@ def test_new_spans_nest_under_their_parents():
     fits = tracer.spans(name="impute:fit")
     assert {s.args["fitted"] for s in fits} == {True, False}
     assert all(s.args["table"] and s.args["attr"] for s in fits)
-    for s in tracer.spans(name="knn:call"):
+    calls = tracer.spans(name="knn:call")
+    for s in calls:
         a = s.args
         assert a["nq"] <= a["nq_padded"] and a["nq_padded"] % 128 == 0
-        # on the CPU the distances run in XLA: q, qm at the padded rows and
-        # the reference rows r, rm, all float32
-        assert a["h2d_bytes"] == 4 * a["d"] * 2 * (a["nq_padded"] + a["nr"])
+        # on the CPU the distances run in XLA: q, qm at the padded rows,
+        # and the reference rows r, rm once per (fit, attribute), on the
+        # first call, which finds them not yet resident; all float32
+        ref = 0 if a["ref_resident"] else a["nr"]
+        assert a["h2d_bytes"] == 4 * a["d"] * 2 * (a["nq_padded"] + ref)
+    # every model here imputes one attribute, so one upload per fit
+    assert (sum(not s.args["ref_resident"] for s in calls)
+            == sum(s.args["fitted"] for s in fits))
     for s in tracer.spans(name="session:snapshot"):
         assert s.args["tables"] == 2 and s.args["rows"] > 0
     for s in tracer.spans(name="op:finalize"):
