@@ -8,12 +8,13 @@
 
 For each seed it prints one JSON line with the compared numbers of a run of
 the program (a window at the cell's own load, then the usual check), or,
-with ``--control 1``, of the control: the reference itself put in the
-program's place, its distances computed in the expanded form in three
-bfloat16 passes (``precision="high"``), over the first ``--queries`` queries
-of the same stream, as many as a run of the cell completes.  A limit lies
-at or above the largest program reading and below the smallest control
-reading (PERF.md).
+with ``--control 1``, of the control: the imputer kind's control imputation
+(``imputers/<kind>.py``, ``reference(..., control=True)``; for k-NN the
+reference's distances in the expanded form in three bfloat16 passes) put in
+the program's place, over the first ``--queries`` queries of the same
+stream, as many as a run of the cell completes; a kind with no control
+exits with a message.  A limit lies at or above the largest program
+reading and below the smallest control reading (PERF.md).
 """
 
 from __future__ import annotations
@@ -36,7 +37,14 @@ def control_checks(spec: dict, seed: int, queries: int, log) -> tuple:
                                       spec["traffic"], seed)
     records = [{"query": q, "shape": run._shape(q)}
                for q in (next(stream) for _ in range(queries))]
-    low = Reference(tables, spec["config"]["imputer"]["k"], precision="high")
+    try:
+        control = spec["imputer"].reference(
+            tables, spec["config"]["imputer"], control=True)
+    except NotImplementedError as e:
+        raise SystemExit(f"bench: imputer kind "
+                         f"{spec['config']['imputer']['kind']!r} has no "
+                         f"control: {e}") from e
+    low = Reference(tables, control)
     return run._checks(spec, records,
                        lambda rs: [low.answer(r["query"]) for r in rs],
                        log)

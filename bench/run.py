@@ -9,7 +9,8 @@ and the service's settings, ``configs/``) and a traffic mix (``traffic/``);
 ``cells/<workload>.json`` holds its comparison limits.  A run:
 
 1. set-up (``setup_s``, from process start): generates the tables from the
-   seed, builds ``QuipService``, and runs every device program the window
+   seed, builds ``QuipService`` with the imputer of the configuration's
+   kind (``imputers/<kind>.py``), and runs every device program the window
    can call once (``sut.warm_up``);
 2. the window: one client in a closed loop, no think time, submits the
    next query of the stream, waits for its result, and submits the next,
@@ -20,10 +21,11 @@ and the service's settings, ``configs/``) and a traffic mix (``traffic/``);
    window and the JAX profiler for its first ``TRACE_SECONDS``; the
    per-layer metrics (``metrics/<name>.py``) read them;
 4. the check: after the device's peak memory is read and the service is
-   freed, the plain reference (``reference.py``) evaluates every query
-   completed in the window, and ``compare.items_off`` counts the answer
-   items that no admissible completion of the tables gives.  ``correct`` holds when
-   every compared number is within its limit (both are exact: 0).
+   freed, the plain reference (``reference.py`` over the kind's reference
+   imputation) evaluates every query completed in the window, and
+   ``compare.items_off`` counts the answer items that no admissible
+   completion of the tables gives.  ``correct`` holds when every compared
+   number is within its limit (both are exact: 0).
 
 The last line on stdout is the result as JSON; the last lines on stderr are
 the compared numbers beside their limits.  Without an accelerator, or with
@@ -86,10 +88,21 @@ def _module(path: str, name: str):
     return mod
 
 
+def imputer(kind: str, root: str = ROOT):
+    """The imputer kind ``bench/imputers/<kind>.py``: ``factory``,
+    ``warm_up``, ``context`` and ``reference``."""
+    path = os.path.join(root, "bench", "imputers", kind + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(
+            f"no imputer kind {kind!r}: add bench/imputers/{kind}.py with "
+            f"factory, warm_up, context and reference (bench/README.md)")
+    return _module(path, "imputer_" + kind)
+
+
 def load_spec(workload: str, root: str = ROOT) -> dict:
     """Everything one cell needs, from ``BENCHMARK.json`` and the files its
     entries name: the configuration, the traffic mix, the cell's limits, the
-    dataset generator and the metrics it reports."""
+    dataset generator, the imputer kind and the metrics it reports."""
     here = os.path.join(root, "bench")
     bench = _json(os.path.join(root, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -113,6 +126,7 @@ def load_spec(workload: str, root: str = ROOT) -> dict:
         "generator": _module(os.path.join(
             here, "datagen", config["generator"] + ".py"),
             "datagen_" + config["generator"]),
+        "imputer": imputer(config["imputer"]["kind"], root),
         "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
         "per_layer": [m for m in bench["per_layer"] if applies(m)],
     }
@@ -260,7 +274,9 @@ def _checks(spec: dict, records: list, answers_of, log) -> tuple:
     ``answers_of(records) -> answers`` gives what is checked."""
     done = [r for r in records if "error" not in r]
     t0 = time.perf_counter()
-    ref = Reference(spec["tables"], spec["config"]["imputer"]["k"])
+    imputation = spec["imputer"].reference(spec["tables"],
+                                           spec["config"]["imputer"])
+    ref = Reference(spec["tables"], imputation)
     items = 0
     off = {"aggregates": 0, "projections": 0}
     for i, (r, answer) in enumerate(zip(done, answers_of(done))):
@@ -271,7 +287,7 @@ def _checks(spec: dict, records: list, answers_of, log) -> tuple:
         if bad:
             log(f"answer off: query {i} ({r['shape']}) {bad} of {n} items: "
                 f"{json.dumps(r['query'])[:300]}")
-    amb, n_open, imputed = ref.ambiguous()
+    amb, n_open, imputed = imputation.ambiguous()
     detail = {"queries": len(done), "items": items,
               "items_off_aggregates": off["aggregates"],
               "items_off_projections": off["projections"],
@@ -280,8 +296,8 @@ def _checks(spec: dict, records: list, answers_of, log) -> tuple:
               "seconds": time.perf_counter() - t0}
     log(f"reference: {len(done)} queries, {items} answer items compared in "
         f"{detail['seconds']:.1f} s; {amb} of {imputed} imputed cells "
-        f"ambiguous at float32, {n_open} of them open; items off: "
-        f"{off['aggregates']} in aggregates, {off['projections']} in "
+        f"ambiguous at the stated precision, {n_open} of them open; items "
+        f"off: {off['aggregates']} in aggregates, {off['projections']} in "
         f"projections")
     values = {"failed_queries": len(records) - len(done),
               "answer_items_off": off["aggregates"] + off["projections"]}
@@ -309,8 +325,8 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
             from repro.obs.trace import Tracer
 
             tracer = Tracer(enabled=True)
-        svc = sut.make_service(rels, config, tracer)
-        warmed = sut.warm_up(tables, config)
+        svc = sut.make_service(rels, config, tracer, spec["imputer"])
+        warmed = sut.warm_up(tables, config, spec["imputer"])
         stream = querygen.QueryStream(tables, config["joins"], mix, seed)
         s0 = svc.summary()
         setup_s = time.perf_counter() - T_START
@@ -360,10 +376,9 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
             "imputations": s1["imputations"] - s0["imputations"],
             "compiles": in_window,
             "trace": reduced,
-            "knn_shapes": sut.knn_shapes(tables),
-            "k": config["imputer"]["k"],
             "device_kind": device["kind"],
             "log": log,
+            **spec["imputer"].context(tables, config["imputer"]),
         }
         for m in spec["per_layer"]:
             value = reader(m["name"]).read(ctx)
@@ -396,7 +411,11 @@ def main(argv=None) -> int:
         print(f"bench: the service runs at its defaults; unset {leaked}",
               file=sys.stderr)
         return 2
-    spec = load_spec(args.workload)
+    try:
+        spec = load_spec(args.workload)
+    except FileNotFoundError as e:
+        print(f"bench: {e}", file=sys.stderr)
+        return 2
     sut.import_program(ROOT)
     try:
         check_device(spec["chips"])

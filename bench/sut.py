@@ -1,13 +1,14 @@
 """The system under test as the benchmark drives it: QUIP's serving path,
 ``repro.service.QuipService``, over ``MaskedRelation`` tables built from the
-generated data.  The only module of the benchmark that imports the program;
-from it the benchmark takes the service, its spans and counters, and the
-kernel entry points it warms.
+generated data.  The only module of the benchmark that imports the program,
+with the imputer kinds (``imputers/<kind>.py``), which import it only inside
+the ``factory`` and ``warm_up`` that this module calls; from it the
+benchmark takes the service, its spans and counters, and the kernel entry
+points it warms.
 """
 
 from __future__ import annotations
 
-import functools
 import os
 import sys
 
@@ -57,53 +58,26 @@ def to_query(q: dict):
                  projection=tuple(q["projection"]), aggregate=agg)
 
 
-def make_service(rels: dict, config: dict, tracer):
-    """``QuipService`` with the configuration's settings and k-NN imputer.
-    ``tracer`` is a program ``Tracer`` or False."""
-    from repro.imputers import KnnImputer
+def make_service(rels: dict, config: dict, tracer, kind):
+    """``QuipService`` with the configuration's settings and the imputer of
+    its kind (``imputers/<kind>.py``, ``factory``).  ``tracer`` is a program
+    ``Tracer`` or False."""
     from repro.service import QuipService
 
-    imp = config["imputer"]
-    factory = functools.partial(KnnImputer, k=imp["k"], batch=imp["batch"],
-                                cost_per_value=imp["cost_per_value"])
-    return QuipService(rels, factory, tracer=tracer, **config["service"])
+    return QuipService(rels, kind.factory(config["imputer"]), tracer=tracer,
+                       **config["service"])
 
 
-def knn_shapes(tables: dict) -> dict:
-    """``attr -> (reference rows, features)`` for every attribute with a
-    missing cell: the sizes of the imputer's device programs."""
-    out = {}
-    for tab in tables.values():
-        d = len(tab["columns"]) - 1
-        for c, m in tab["missing"].items():
-            if m.any():
-                out[c] = (int((~m).sum()), d)
-    return out
-
-
-def warm_up(tables: dict, config: dict) -> int:
+def warm_up(tables: dict, config: dict, kind) -> int:
     """Run every device program the window can call once, at each of its
     shapes, so that nothing compiles in the window.  Returns how many.
 
-    k-NN: one masked-distance and one top-k program per (attribute with a
-    missing cell, query bucket): the imputer pads query batches of at most
-    ``batch`` rows to powers of two from 128, and reference rows are not
-    padded.  Bloom probe: one program per probe bucket, powers of two from
-    512 up to the largest table's row count (a probe checks rows of one
-    join side)."""
+    The imputer's programs are its kind's (``warm_up``).  Bloom probe: one
+    program per probe bucket, powers of two from 512 up to the largest
+    table's row count (a probe checks rows of one join side)."""
     from repro.core.bloom import BloomFilter
-    from repro.kernels import ops as kops
 
-    imp = config["imputer"]
-    n = 0
-    for nr, d in knn_shapes(tables).values():
-        r = np.zeros((nr, d), np.float32)
-        nq = 128
-        while nq <= imp["batch"]:
-            q = np.zeros((nq, d), np.float32)
-            kops.masked_knn(q, q, r, r, min(imp["k"], nr))
-            n += 2
-            nq *= 2
+    n = kind.warm_up(tables, config["imputer"])
     bf = BloomFilter("warm")
     most = max(len(m) for tab in tables.values()
                for m in tab["missing"].values())

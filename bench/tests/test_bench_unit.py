@@ -23,6 +23,13 @@ import run  # noqa: E402
 import work  # noqa: E402
 from reference import Reference  # noqa: E402
 
+KNN = run.imputer("knn")
+
+
+def _knn_ref(tables: dict, k: int) -> Reference:
+    """The SPJA reference over the k-NN kind's reference imputation."""
+    return Reference(tables, KNN.reference(tables, {"k": k}))
+
 
 # --------------------------------------------------------------------------- #
 # trace reduction
@@ -160,7 +167,7 @@ def _table(cols: dict, missing: dict, kinds: dict) -> dict:
 def _join_ref():
     a = _table({"a.k": [1, 2, 3], "a.v": [5, 6, 7]}, {}, {})
     b = _table({"b.k": [1, 1, 3, 4], "b.w": [1, 2, 3, 4]}, {}, {})
-    return Reference({"a": a, "b": b}, k=1)
+    return _knn_ref({"a": a, "b": b}, k=1)
 
 
 JOIN_Q = {"tables": ["a", "b"], "joins": [["a.k", "b.k"]],
@@ -201,7 +208,7 @@ def _tie_ref():
     """Row 1 misses t.y; with k=1 rows 0 and 2 are exactly as near."""
     t = _table({"t.x": [0, 1, 2, 3], "t.y": [10, 0, 30, 40]},
                {"t.y": [False, True, False, False]}, {})
-    return Reference({"t": t}, k=1)
+    return _knn_ref({"t": t}, k=1)
 
 
 def test_reference_admits_either_side_of_an_exact_tie():
@@ -227,27 +234,27 @@ def test_reference_admits_either_side_of_an_exact_tie():
 
 def test_band_from_the_float32_bound_of_a_column_sum():
     # 7 sqrt(n) + 6 roundoffs of the distance, 8 of the magnitude
-    assert reference.rel_units(4) == 20.0
-    assert reference.rel_units(50_000) == pytest.approx(1571.25, abs=0.01)
-    got = reference.band(np.array([1.0, 0.0]), np.array([0.0, 2.0]), 4)
-    assert got.tolist() == [20 * reference.EPS32, 16 * reference.EPS32]
+    assert KNN.rel_units(4) == 20.0
+    assert KNN.rel_units(50_000) == pytest.approx(1571.25, abs=0.01)
+    got = KNN.band(np.array([1.0, 0.0]), np.array([0.0, 2.0]), 4)
+    assert got.tolist() == [20 * KNN.EPS32, 16 * KNN.EPS32]
 
 
 def test_open_cell_is_bounded_by_the_attribute_range():
     # every reference row is as near as any other: more candidates lie in
     # the band than are fetched, so row 0 may take any of them
-    n = reference.EXTRA + 4
+    n = KNN.EXTRA + 4
     ys = [0] + list(range(10, 10 + n - 1))
     t = _table({"t.x": [0] * n, "t.y": ys},
                {"t.y": [True] + [False] * (n - 1)}, {})
-    col = Reference({"t": t}, k=1).column("t.y")
+    col = _knn_ref({"t": t}, k=1).column("t.y")
     assert col.open.tolist() == [True] + [False] * (n - 1)
     assert (col.lo[0], col.hi[0]) == (10.0, 10.0 + n - 2)
     assert col.admits(np.array([0, 0, 0]),
                       np.array([10, 10 + n - 2, 10 + n - 1])).tolist() == [
                           True, True, False]
     # a sum over the open cell is bounded, not any value
-    ref = Reference({"t": t}, k=1)
+    ref = _knn_ref({"t": t}, k=1)
     exp = ref.expect({"tables": ["t"], "joins": [], "selections": [],
                       "projection": [], "aggregate": ["sum", "t.y", None]})
     rest = sum(ys[1:])
@@ -256,7 +263,7 @@ def test_open_cell_is_bounded_by_the_attribute_range():
     f = _table({"f.x": [0.0] * n, "f.y": [0.0] + [float(y) for y in ys[1:]]},
                {"f.y": [True] + [False] * (n - 1)},
                {"f.x": "float", "f.y": "float"})
-    fcol = Reference({"f": f}, k=2).column("f.y")
+    fcol = _knn_ref({"f": f}, k=2).column("f.y")
     assert fcol.open[0] and (fcol.lo[0], fcol.hi[0]) == (10.0, 10.0 + n - 2)
 
 
@@ -321,22 +328,22 @@ def test_reference_knn_hand_computed():
     # mode of {20, 30} ties and goes to the smaller value.
     t = _table({"t.x": [0, 1, 2, 3, 5], "t.y": [10, 20, 0, 30, 30]},
                {"t.y": [False, False, True, False, False]}, {})
-    ref = Reference({"t": t}, k=2)
+    ref = _knn_ref({"t": t}, k=2)
     assert ref.column("t.y").val.tolist() == [10, 20, 20, 30, 30]
     assert not ref.column("t.y").amb.any()
     # k=3: row 0 joins; mode of {20, 30, 10} ties three ways -> 10
-    assert Reference({"t": t}, k=3).column("t.y").val[2] == 10
+    assert _knn_ref({"t": t}, k=3).column("t.y").val[2] == 10
     # a float attribute takes the mean of its neighbours
     f = _table({"f.x": [0, 1, 2, 3], "f.y": [1.0, 2.0, 0.0, 4.0]},
                {"f.y": [False, False, True, False]}, {"f.y": "float"})
-    assert Reference({"f": f}, k=2).column("f.y").val[2] == pytest.approx(
+    assert _knn_ref({"f": f}, k=2).column("f.y").val[2] == pytest.approx(
         3.0)
 
 
 def test_reference_evaluates_spja():
     a = _table({"a.k": [1, 2, 3], "a.v": [5, 6, 7]}, {}, {})
     b = _table({"b.k": [1, 1, 3, 4], "b.w": [1, 2, 3, 4]}, {}, {})
-    ref = Reference({"a": a, "b": b}, k=1)
+    ref = _knn_ref({"a": a, "b": b}, k=1)
     base = {"tables": ["a", "b"], "joins": [["a.k", "b.k"]],
             "selections": [["b.w", ">=", 2]], "projection": ["a.v", "b.w"],
             "aggregate": None}
