@@ -14,11 +14,11 @@ from repro.core.executor import execute_offline, execute_quip, make_plan
 from repro.core.plan import Query
 from repro.core.relation import MaskedRelation
 from repro.imputers import (
-    GbdtImputer,
     ImputationEngine,
     KnnImputer,
     LocaterImputer,
     MeanImputer,
+    XgbHistImputer,
 )
 
 __all__ = ["IMPUTER_FACTORIES", "run_workload", "StrategyResult"]
@@ -29,8 +29,8 @@ __all__ = ["IMPUTER_FACTORIES", "run_workload", "StrategyResult"]
 IMPUTER_FACTORIES: Dict[str, Callable[[], object]] = {
     "mean": lambda: MeanImputer(),
     "knn": lambda: KnnImputer(k=5, cost_per_value=2e-3),
-    "xgboost": lambda: GbdtImputer(rounds=16, train_cost=1.0,
-                                   cost_per_value=2e-5),
+    "xgboost": lambda: XgbHistImputer(rounds=16, train_cost=1.0,
+                                      cost_per_value=2e-5),
     "locater": lambda: LocaterImputer(cost_per_value=4e-3),
 }
 

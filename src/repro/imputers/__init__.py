@@ -6,8 +6,8 @@ from repro.imputers.base import (
 )
 from repro.imputers.mean import MeanImputer
 from repro.imputers.knn import KnnImputer
-from repro.imputers.gbdt import GbdtImputer
 from repro.imputers.locater import LocaterImputer
+from repro.imputers.xgb_hist import XgbHistImputer
 
 __all__ = [
     "ImputationEngine",
@@ -16,6 +16,6 @@ __all__ = [
     "ImputeStore",
     "MeanImputer",
     "KnnImputer",
-    "GbdtImputer",
     "LocaterImputer",
+    "XgbHistImputer",
 ]

@@ -5,8 +5,9 @@ Imputers follow the paper's blocking / non-blocking taxonomy (§2.1):
 * non-blocking — impute per tuple(-batch) from local/streamed state
   (mean-by-histogram, LOCATER-style time series);
 * blocking — require a training pass over the table first (KNN's reference
-  matrix, GBDT).  Training cost is charged once on first use; inference cost
-  per value afterwards.
+  matrix, XGBoost-hist's trees per attribute: ``Imputer.fit_attr``).
+  Training cost is charged once on first use; inference cost per value
+  afterwards.
 
 The service is columnar and batched: per (table, attr) it keeps a dense
 value array plus a filled-bitmask the size of the base table (no Python
@@ -64,6 +65,12 @@ class Imputer:
 
     def fit(self, table: MaskedRelation) -> None:  # pragma: no cover
         pass
+
+    def fit_attr(self, table: MaskedRelation, attr: str) -> None:
+        """Train what imputing ``attr`` needs, right after ``fit`` on the
+        model's first use for ``(table, attr)``: a blocking imputer whose
+        model is per attribute trains it here, inside the caller's
+        ``impute:fit`` span, not in the first flush's ``impute_attr``."""
 
     def impute_attr(
         self, table: MaskedRelation, attr: str, tids: np.ndarray
@@ -264,13 +271,14 @@ class ImputeStore:
     # -- model registry ---------------------------------------------------#
     def model_for(self, table: str, attr: str,
                   default: Callable[[], "Imputer"],
-                  per_attr: Dict[str, "Imputer"]
+                  per_attr: Dict[str, "Imputer"], tracer=None
                   ) -> Tuple["Imputer", Optional[float]]:
         """Fitted model for ``table.attr``; returns ``(model, train_wall)``
         where ``train_wall`` is the fit's wall seconds on the call that
         trained it and ``None`` otherwise (the caller charges training cost
         to its own query's counters — under a shared store only the first
-        query pays).
+        query pays).  A fit spans its work on ``tracer`` (the model's
+        ``tracer`` from then on) where one is given.
 
         Callers hold the key's :meth:`flush_lock`, which serializes the
         fit of a given (table, attr) model; only the registry dicts need
@@ -289,8 +297,11 @@ class ImputeStore:
                 self._fitted.add(fit_key)
         train_wall: Optional[float] = None
         if need_fit:
+            if tracer is not None:
+                model.tracer = tracer
             t0 = time.perf_counter()
             model.fit(self.tables[table])
+            model.fit_attr(self.tables[table], attr)
             train_wall = time.perf_counter() - t0
         return model, train_wall
 
@@ -368,7 +379,7 @@ class ImputationService:
         with (tr.span("impute:fit", cat="impute", table=table, attr=attr)
               if tr.enabled else NULL_SPAN) as sp:
             model, train_wall = self.store.model_for(
-                table, attr, self._default, self._per_attr
+                table, attr, self._default, self._per_attr, tracer=tr
             )
             if tr.enabled:
                 sp.set(fitted=train_wall is not None)

@@ -38,6 +38,7 @@ from repro.kernels.neighbor_agg import (  # noqa: E402
     neighbor_mean_pallas,
     neighbor_mode_pallas,
 )
+from repro.kernels import gbdt_hist  # noqa: E402
 from repro.kernels import ref as kref  # noqa: E402
 from repro.kernels.segment_ops import segment_reduce_pallas  # noqa: E402
 
@@ -54,6 +55,9 @@ SEGMENT_GROUPS = 4096  # users.name / users.mac_addr groups (4,000)
 JOIN_BUILD = 100_000  # occupancy side of the lid join
 JOIN_PROBE = 4096
 JOIN_MAX_DUP = 4096  # occupancy rows per lid (~3,300)
+GBDT_ROWS = 16384  # the smallest boosting bucket (NHANES: 1,107-15,229)
+GBDT_FEATURES = 8  # NHANES: a table's other attributes
+GBDT_ROUNDS = 100
 
 
 @pytest.fixture(scope="module")
@@ -161,3 +165,22 @@ def test_hash_join_probe_compiles(spec):
                            max_dup=JOIN_MAX_DUP, interpret=False)
     _compile(fn, spec((cap,), jnp.uint32), spec((cap,), jnp.int32),
              spec((JOIN_PROBE,), jnp.uint32))
+
+
+def test_gbdt_boost_and_predict_compile(spec):
+    """The XGBoost-hist programs (plain XLA, no kernel) at the NHANES
+    configuration's settings: the boosting program at its smallest
+    bucket, the routing program at its largest batch."""
+    boost = functools.partial(
+        gbdt_hist.boost, rounds=GBDT_ROUNDS, depth=6, eta=0.3, lam=1.0,
+        gamma=0.0, min_child_weight=1.0)
+    rows = spec((GBDT_ROWS,))
+    compiled = _compile(boost, spec((GBDT_ROWS, GBDT_FEATURES), jnp.int16),
+                        rows, rows, rows, spec(()), spec((), jnp.int32),
+                        kernel=False)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+    trees = spec((GBDT_ROUNDS, 63), jnp.int32)
+    _compile(gbdt_hist.predict, trees, trees,
+             spec((GBDT_ROUNDS, 63), jnp.bool_),
+             spec((gbdt_hist.PREDICT_BATCH, GBDT_FEATURES), jnp.int16),
+             kernel=False)
