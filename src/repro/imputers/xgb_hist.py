@@ -132,7 +132,8 @@ class XgbHistImputer(Imputer):
             n_chunks = np.int32(kg.filled_chunks(len(rows)))
             sp.add(rounds=p["rounds"], h2d_bytes=bins.nbytes + g_hi.nbytes
                    + g_lo.nbytes + h.nbytes)
-            with (tr.span("gbdt:boost", cat="kernel", bucket=bucket)
+            with (tr.span("gbdt:boost", cat="kernel", bucket=bucket,
+                          onehot_bytes=bins.size * kg.SLOTS)
                   if tr.enabled else NULL_SPAN):
                 feat, thr, dleft, leaves = (np.asarray(a) for a in kg.boost(
                     bins, g_hi, g_lo, h, q, n_chunks, **p))

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import pytest
 
@@ -56,6 +57,7 @@ JOIN_BUILD = 100_000  # occupancy side of the lid join
 JOIN_PROBE = 4096
 JOIN_MAX_DUP = 4096  # occupancy rows per lid (~3,300)
 GBDT_ROWS = 16384  # the smallest boosting bucket (NHANES: 1,107-15,229)
+GBDT_BIG_ROWS = 65536  # the bucket of NHANES' largest tables (~47,000 rows)
 GBDT_FEATURES = 8  # NHANES: a table's other attributes
 GBDT_ROUNDS = 100
 
@@ -167,18 +169,45 @@ def test_hash_join_probe_compiles(spec):
              spec((JOIN_PROBE,), jnp.uint32))
 
 
+def _buffers(text):
+    """The result shapes of a compiled program's instructions outside its
+    fused computations: the arrays it writes to memory."""
+    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", text))
+    out, comp = [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        inst = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (\S+)", line)
+        if inst and comp not in fused:
+            out.append(inst.group(1))
+    return out
+
+
 def test_gbdt_boost_and_predict_compile(spec):
     """The XGBoost-hist programs (plain XLA, no kernel) at the NHANES
-    configuration's settings: the boosting program at its smallest
-    bucket, the routing program at its largest batch."""
+    configuration's settings: the boosting program at its smallest bucket
+    and at 65,536 rows, the routing program at its largest batch.  At
+    65,536 rows the bin one-hot is built once, a byte an entry, and no
+    chunk loop relays out a one-hot of its own."""
     boost = functools.partial(
         gbdt_hist.boost, rounds=GBDT_ROUNDS, depth=6, eta=0.3, lam=1.0,
         gamma=0.0, min_child_weight=1.0)
-    rows = spec((GBDT_ROWS,))
-    compiled = _compile(boost, spec((GBDT_ROWS, GBDT_FEATURES), jnp.int16),
-                        rows, rows, rows, spec(()), spec((), jnp.int32),
-                        kernel=False)
-    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+    for n in (GBDT_ROWS, GBDT_BIG_ROWS):
+        rows = spec((n,))
+        compiled = _compile(boost, spec((n, GBDT_FEATURES), jnp.int16),
+                            rows, rows, rows, spec(()), spec((), jnp.int32),
+                            kernel=False)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 2**30
+    cols = GBDT_FEATURES * gbdt_hist.SLOTS
+    text = compiled.as_text()
+    assert not re.search(
+        rf"= \w+\[{gbdt_hist.CHUNK},{cols}\]\S* reshape\(", text)
+    assert not [b for b in _buffers(text)
+                if b.startswith(f"s32[{GBDT_BIG_ROWS},{cols}]")]
+    assert temp < 2 * GBDT_BIG_ROWS * cols
     trees = spec((GBDT_ROUNDS, 63), jnp.int32)
     _compile(gbdt_hist.predict, trees, trees,
              spec((GBDT_ROUNDS, 63), jnp.bool_),

@@ -10,6 +10,7 @@ import os
 import sys
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -116,9 +117,10 @@ def test_histogram_sums_are_exact_to_float32_rounding():
     digits = np.asarray(kg._digits(hi, lo, np.float32(1 / q)))
     assert np.abs(digits.astype(np.float64)).max() <= 128
     node = rng.integers(0, nodes, n).astype(np.int32)
-    slot = rng.integers(0, kg.SLOTS, n)
+    slot = rng.integers(0, kg.SLOTS, n).astype(np.int32)
     w = np.concatenate([digits, np.ones((n, 1), digits.dtype)], axis=1)
-    sums = np.asarray(kg._node_sums(w, node, nodes, 1, slot[:, None]))
+    sums = np.asarray(kg._node_sums(w, node, nodes, 1,
+                                    kg._onehot(slot[:, None])))
     G = np.asarray(kg._value(np.moveaxis(sums[:, :kg.LIMBS], 1, -1),
                              np.float32(q)))
     exact = np.zeros((nodes, kg.SLOTS))
@@ -129,6 +131,63 @@ def test_histogram_sums_are_exact_to_float32_rounding():
     # one float32 rounding of a sum off by at most q a row
     tol = np.spacing(np.abs(exact).astype(np.float32)) + count * q
     assert (np.abs(G - exact) <= tol).all()
+
+
+def test_onehot_marks_each_rows_bin_of_each_feature():
+    rng = np.random.default_rng(10)
+    bins = rng.integers(0, kg.SLOTS, (300, 3)).astype(np.int32)
+    bins[rng.random(bins.shape) < 0.2] = kg.MISSING
+    got = np.asarray(kg._onehot(bins))
+    assert got.dtype == np.int8 and got.shape == (300, 3 * kg.SLOTS)
+    want = np.zeros((300, 3, kg.SLOTS), np.int8)
+    np.put_along_axis(want, bins[:, :, None], 1, axis=2)
+    assert np.array_equal(got, want.reshape(300, -1))
+
+
+def _node_sums_per_chunk(w, node, nodes, chunks, bins=None):
+    """``kg._node_sums`` from the bins themselves: each chunk's one-hot
+    compared and laid out inside the chunk loop, at every level."""
+    k = w.shape[1]
+    cols = 1 if bins is None else bins.shape[1] * kg.SLOTS
+    ids = jnp.arange(nodes, dtype=node.dtype)
+
+    def add(c, acc):
+        lo = c * kg.CHUNK
+        nd = jax.lax.dynamic_slice_in_dim(node, lo, kg.CHUNK)
+        wc = jax.lax.dynamic_slice_in_dim(w, lo, kg.CHUNK)
+        lhs = ((nd[:, None] == ids).astype(jnp.bfloat16)[:, :, None]
+               * wc[:, None, :]).reshape(kg.CHUNK, nodes * k)
+        if bins is None:
+            rhs = jnp.ones((kg.CHUNK, 1), jnp.bfloat16)
+        else:
+            bc = jax.lax.dynamic_slice_in_dim(bins, lo, kg.CHUNK)
+            rhs = (bc[:, :, None] == jnp.arange(kg.SLOTS, dtype=bc.dtype)
+                   ).astype(jnp.bfloat16).reshape(kg.CHUNK, cols)
+        return acc + jax.lax.dot_general(
+            lhs, rhs, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    out = jax.lax.fori_loop(0, chunks, add,
+                            jnp.zeros((nodes * k, cols), jnp.float32))
+    return out.reshape(nodes, k, cols)
+
+
+@pytest.mark.parametrize("rows, bucket", [(900, None), (6000, 1 << 15)])
+def test_boost_matches_the_per_chunk_histograms(ref, monkeypatch, rows,
+                                                bucket):
+    """The resident one-hot gives the trees and leaves, bit for bit, of
+    histograms whose one-hot is built anew at every chunk: at two buckets,
+    with fewer chunks filled than the bucket has and features missing
+    cells."""
+    args, _fit, n = _fit_inputs(ref, _tables(rows, 11), "T.a", bucket=bucket)
+    assert 0 < int(args[5]) < args[0].shape[0] // kg.CHUNK
+    assert (args[0][:n] == kg.MISSING).any(axis=0).all()
+    got = _boost(args)
+    monkeypatch.setattr(kg, "_onehot", lambda bins: bins)
+    monkeypatch.setattr(kg, "_node_sums", _node_sums_per_chunk)
+    want = jax.jit(lambda *a: kg.boost.__wrapped__(*a, **PARAMS))(*args)
+    for x, y in zip(got, want):
+        assert np.array_equal(x, np.asarray(y))
 
 
 @pytest.mark.parametrize("seed", [1, 2, 3])
@@ -242,6 +301,8 @@ def test_fit_and_predict_spans_nest_under_the_imputation_spans():
     assert fit.args == {"table": "T", "attr": "T.e", "rows": rows,
                         "bucket": bucket, "rounds": 4,
                         "h2d_bytes": bucket * (4 * 2 + 3 * 4)}
+    assert next(s for s in spans if s.name == "gbdt:boost").args == {
+        "bucket": bucket, "onehot_bytes": bucket * 4 * kg.SLOTS}
     assert next(s for s in spans if s.name == "gbdt:predict").args == {
         "rows": len(tids)}
 
