@@ -160,9 +160,8 @@ def _const_str(node: ast.AST) -> Optional[str]:
 # --------------------------------------------------------------------------- #
 #: files allowed to touch os.environ for QUIP_* keys (the parsers)
 ENV_PARSER_FILES = frozenset({"core/env.py"})
-#: files allowed to *mutate* os.environ (import-time XLA host-device flag)
-ENV_MUTATION_FILES = frozenset({"core/env.py", "launch/dryrun.py",
-                                "launch/hillclimb.py"})
+#: files allowed to *mutate* os.environ
+ENV_MUTATION_FILES = frozenset({"core/env.py"})
 _ENV_PARSERS = frozenset({"env_flag", "env_choice", "env_int"})
 _ENVIRON_MUTATORS = frozenset({"setdefault", "pop", "update", "clear"})
 
@@ -174,8 +173,8 @@ def _is_os_environ(node: ast.AST) -> bool:
 
 def env_pass(sources: Dict[str, str]) -> List[Finding]:
     """``QUIP_*`` env reads only via ``core.env``; ``os.environ`` mutation
-    only in the whitelisted import-time launch files; every knob literal
-    registered in ``ENV_REGISTRY``."""
+    only in ``core/env.py``; every knob literal registered in
+    ``ENV_REGISTRY``."""
     findings: List[Finding] = []
     for path, src in sorted(sources.items()):
         tree = _parse(path, src, "env-discipline", findings)
@@ -189,7 +188,7 @@ def env_pass(sources: Dict[str, str]) -> List[Finding]:
                     findings.append(Finding(
                         path, node.lineno, "env-discipline",
                         f"os.environ mutation of {key or '<dynamic>'!s} "
-                        f"outside the whitelisted launch files",
+                        "outside core/env.py",
                     ))
                 elif (isinstance(node.ctx, ast.Load) and key
                         and key.startswith("QUIP_")
@@ -208,8 +207,7 @@ def env_pass(sources: Dict[str, str]) -> List[Finding]:
                     if (fname != "get" and path not in ENV_MUTATION_FILES):
                         findings.append(Finding(
                             path, node.lineno, "env-discipline",
-                            f"os.environ.{fname}() outside the whitelisted "
-                            f"launch files",
+                            f"os.environ.{fname}() outside core/env.py",
                         ))
                     elif (fname == "get" and args0
                           and args0.startswith("QUIP_")

@@ -32,8 +32,8 @@ plain Condition while the held-set bookkeeping stays accurate across
 Tests drive this via the autouse fixtures in ``tests/test_workers.py`` /
 ``tests/test_serving_fuzz.py`` (fast profiles) and CI runs the serving
 fuzz smoke under ``QUIP_SANITIZE=locks``; :func:`assert_acyclic` writes
-the JSON report to ``benchmarks/artifacts/lock_sanitizer_report.json``
-on failure (uploaded as a CI artifact).  See docs/analysis.md.
+the JSON report to ``artifacts/lock_sanitizer_report.json`` on failure
+(uploaded as a CI artifact).  See docs/analysis.md.
 """
 
 from __future__ import annotations
@@ -62,8 +62,7 @@ __all__ = [
 SANITIZE_MODES = ("off", "locks")
 
 #: default artifact path for assert_acyclic failures (CI uploads it)
-REPORT_PATH = os.path.join("benchmarks", "artifacts",
-                           "lock_sanitizer_report.json")
+REPORT_PATH = os.path.join("artifacts", "lock_sanitizer_report.json")
 
 _STACK_LIMIT = 16  # frames captured per first-observed edge
 

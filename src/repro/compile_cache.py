@@ -1,7 +1,7 @@
 """Where JAX keeps its persistent compilation cache.
 
 One rule for every entry point that compiles for the device
-(``chip_smoke.py``, ``benchmarks/run.py``): when
+(``chip_smoke.py``; ``bench/run.py`` keeps its own copy): when
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it into
 ``jax_compilation_cache_dir`` and that path stands; otherwise the cache
 goes to ``<repo>/.jax_cache``.  The path is part of the cache key, so it

@@ -33,7 +33,7 @@ __all__ = [
 
 def nearest_rank_quantile(values: List[float], q: float) -> float:
     """Nearest-rank quantile over ``values`` (0.0 if empty) — the single
-    definition used by both serving telemetry and the benchmarks.
+    definition used by serving telemetry.
 
     True nearest-rank: the ``ceil(q·n)``-th order statistic (1-indexed).
     The previous banker's-rounded ``round(q·(n-1))`` was *not* nearest
@@ -223,8 +223,8 @@ class ServingStats:
 
     Collects one :class:`QueryRecord` per finished query plus service-level
     gauges (observed concurrency, admission queueing).  Plan-cache hit/miss
-    counts live on the cache itself; ``summary`` merges both views into the
-    flat ``serving_*`` metric dict the benchmarks record."""
+    counts live on the cache itself; ``summary`` merges both views into
+    one flat metric dict."""
 
     def __init__(self):
         self.records: List[QueryRecord] = []

@@ -152,7 +152,8 @@ def serving_workload(
 
     * ``tenant_skew`` — Zipf exponent over tenant ids: tenant 0 becomes
       the heavy "aggressor" issuing most of the stream while the high
-      ranks are low-traffic "victims" (exp10's scenario);
+      ranks are low-traffic "victims" (the QoS scenario of
+      ``tests/test_serving_invariants.py``);
     * ``tenant_mix`` — per-tenant template pools (tenant → tuple of
       template indices): each tenant draws only from its pool, with the
       global Zipf weights renormalized over it, so e.g. an aggressor can
@@ -260,7 +261,7 @@ def mutating_workload(
     observed domain) and small deletions.  Deterministic for a fixed seed;
     row ids stay valid by tracking each table's row count as deletions
     shrink it.  This is the workload the staleness tests and
-    ``benchmarks/exp9_result_cache.py`` replay: every mutation bumps the
+    ``tests/test_serving_invariants.py`` replay: every mutation bumps the
     table's epoch, so a correct service must re-plan, re-impute, and
     re-answer — while a stale cache would keep serving the old epoch.
     """

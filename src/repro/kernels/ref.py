@@ -158,27 +158,3 @@ def segment_reduce_ref(
         return jax.ops.segment_max(vals, seg_ids, num_segments=num_segments)
     raise ValueError(f"unknown segment op {op!r}")
 
-
-def attention_ref(q, k, v, causal: bool = True, window=None, scale=None):
-    """Oracle for the flash-attention kernel: materialized-softmax GQA.
-
-    q: (B, S, H, D); k/v: (B, S, KV, D) → (B, S, H, D)."""
-    b, s, h, d = q.shape
-    kv = k.shape[2]
-    rep = h // max(kv, 1)
-    if scale is None:
-        scale = 1.0 / (d ** 0.5)
-    qg = q.astype(jnp.float32).reshape(b, s, kv, rep, d)
-    logits = jnp.einsum("bskrd,btkd->bkrst", qg,
-                        k.astype(jnp.float32)) * scale
-    qpos = jnp.arange(s)[:, None]
-    kpos = jnp.arange(s)[None, :]
-    ok = jnp.ones((s, s), dtype=bool)
-    if causal:
-        ok &= kpos <= qpos
-    if window is not None:
-        ok &= kpos > qpos - window
-    logits = jnp.where(ok[None, None, None], logits, -1e30)
-    w = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkrst,btkd->bskrd", w, v.astype(jnp.float32))
-    return out.reshape(b, s, h, d).astype(q.dtype)

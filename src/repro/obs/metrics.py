@@ -14,7 +14,7 @@ Two render formats:
 * ``snapshot()`` — a JSON-able dict ``{name: {type, help, value|values|…}}``;
 * ``prometheus()`` — the Prometheus text exposition format
   (``# HELP`` / ``# TYPE`` + samples), validated by
-  ``benchmarks/exp13_obs.py`` and the CI smoke step.
+  ``tests/test_obs_invariants.py``.
 
 The full metric-name catalog lives in docs/observability.md.
 """

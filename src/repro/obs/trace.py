@@ -11,8 +11,8 @@ Design constraints, in order:
   morsel hot path.  ``Tracer.span(...)`` returns the shared
   :data:`NULL_SPAN` singleton when disabled, and every hot call site
   additionally guards with ``if tracer.enabled`` so the keyword-argument
-  dict is never even built.  The overhead gate in ``benchmarks/exp13_obs.py``
-  asserts this contract.
+  dict is never even built.  The overhead gate in
+  ``tests/test_obs_invariants.py`` asserts this contract.
 * **Deterministic structure.**  ``clock="unit"`` replaces ``perf_counter``
   with a lock-guarded monotone tick, so CI asserts on span *counts and
   nesting* (:meth:`span_counts`, :meth:`span_tree`), never on wall time.

@@ -47,7 +47,7 @@ Charging (``cost_model``)
     time always advances) — deterministic across runs.
 ``unit``
     One ticket per step — deterministic step-share accounting, what the
-    fairness tests and ``benchmarks/exp10_qos.py`` assert on (no wall
+    fairness tests and ``tests/test_serving_invariants.py`` assert on (no wall
     clock anywhere).
 
 The scheduler ``clock`` advances by the charged cost of every step, so

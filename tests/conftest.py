@@ -2,8 +2,8 @@
 and a SIGALRM fallback for ``@pytest.mark.timeout``.
 
 Tier-1 (`PYTHONPATH=src python -m pytest -q`) runs the fast suite; cases
-marked ``@pytest.mark.slow`` (full per-architecture sweeps, long-prefix
-decode equivalence, long optimizer convergence) are skipped unless
+marked ``@pytest.mark.slow`` (the deep serving-fuzz, worker-pool and
+tracing profiles, a 3-table kernel-join case) are skipped unless
 ``--runslow`` is passed.
 
 The threaded worker-pool tests carry ``@pytest.mark.timeout(N)`` so a

@@ -56,8 +56,10 @@ def test_env_pass_flags_mutation_outside_launch_whitelist():
     src = 'import os\nos.environ["XLA_FLAGS"] = "x"\n'
     f = PASSES["env-discipline"]({"service/x.py": src})
     assert len(f) == 1 and "mutation" in f[0].message
-    # the import-time launch shims are whitelisted
-    assert PASSES["env-discipline"]({"launch/dryrun.py": src}) == []
+    # core/env.py is the only whitelisted file; any other path is flagged
+    assert PASSES["env-discipline"]({"core/env.py": src}) == []
+    f = PASSES["env-discipline"]({"launch/dryrun.py": src})
+    assert len(f) == 1 and "mutation" in f[0].message
 
 
 def test_env_pass_flags_unregistered_knob():

@@ -1,5 +1,4 @@
-"""``chip_smoke.py`` on the CPU and the compile-cache rule it shares with
-``benchmarks/run.py``.
+"""``chip_smoke.py`` on the CPU and the compile-cache rule it follows.
 
 The smoke's phases run end to end at a tiny size (Pallas in interpret
 mode, XLA on the CPU) so that a change which breaks its control flow or

@@ -1,50 +1,40 @@
-"""Experiment 14: delta-driven result-cache maintenance (QUIP_IVM).
+"""IVM acceptance invariants: delta-driven result-cache maintenance.
 
-A mutation-heavy repeat workload crafted so patching is *possible*: the
-mutated table (``R0``) is fully present, while all missing values live on
-the join partner (``R1``), which is never mutated.  Every cached answer
-then depends on ``R0`` only through its stored values — the
-imputation-interaction fallback cannot fire — so the IVM maintainer can
-patch count/sum/avg aggregates and select/project answers in place
-instead of evicting them.
+A mutation-heavy repeat workload built so that patching is possible: the
+mutated table ``R0`` has no missing cells, and every missing value lives
+on its join partner ``R1``, which is never mutated.  A cached answer then
+depends on ``R0`` only through stored values, so the imputation-interaction
+fallback cannot fire and count/sum/avg aggregates and select/project
+answers can be patched in place.
 
-The identical event stream (repeat-heavy query templates from a skewed
-draw, interleaved with update/delete/insert commits on ``R0``) is
-replayed against two services — ``ivm=False`` (evict-on-mutation, the
-pre-IVM behaviour) and ``ivm=True`` — plus a cold replay oracle per
-query.  Acceptance (asserted in ``derived``; CI runs this module as a
-smoke check):
-
-* ``results_patched > 0`` for the IVM service — maintenance actually ran;
-* zero stale answers: every IVM-on answer is bit-identical to a cold
-  execution over the post-mutation tables (and to the IVM-off service);
-* hit-rate gain: the IVM service serves strictly more result-cache hits
-  than the evicting service on the same stream — the point of patching.
+One event stream (skewed repeats over six templates, with an update,
+delete or insert commit on ``R0`` every fourth query) is replayed against
+a service that evicts on mutation (``ivm=False``) and one that patches
+(``ivm=True``); the patching service's every answer is also compared with
+a cold execution over the post-mutation tables.
 """
 
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Tuple
 
 import numpy as np
+import pytest
 
-from benchmarks.common import IMPUTER_FACTORIES
 from repro.core.executor import execute_quip
 from repro.core.plan import Aggregate, Query
 from repro.core.predicates import JoinPredicate, SelectionPredicate
 from repro.core.relation import MaskedRelation
 from repro.core.schema import ColumnSpec, Schema
 from repro.imputers.base import ImputationService
+from repro.imputers.mean import MeanImputer
 from repro.service import QuipService, TableRegistry
-
-NAME = "exp14_ivm"
 
 STRATEGY = "adaptive"
 MORSEL_ROWS = 4096
-IMPUTER = "mean"
 KEY_CARD = 8
 VAL_CARD = 32
+ROWS = 1500
 
 
 def _instance(rows: int, missing_rate: float, seed: int
@@ -63,8 +53,7 @@ def _instance(rows: int, missing_rate: float, seed: int
         }
         missing = None
         if name == "R1":
-            mask = rng.random(rows) < missing_rate
-            missing = {f"{name}.v": mask}
+            missing = {f"{name}.v": rng.random(rows) < missing_rate}
         tables[name] = MaskedRelation.from_columns(
             schema, cols, missing=missing, base_table=name
         )
@@ -93,9 +82,8 @@ def _templates() -> List[Query]:
 
 def _events(n_queries: int, mutate_every: int, rows: int, seed: int
             ) -> List[Tuple]:
-    """One deterministic stream applied to every service: skewed repeats
-    over the templates, a mutation commit on R0 every ``mutate_every``
-    queries (update- heavy, some deletes and inserts)."""
+    """Skewed repeats over the templates, and a commit on R0 every
+    ``mutate_every`` queries (mostly updates, some deletes and inserts)."""
     rng = np.random.default_rng(seed)
     templates = _templates()
     weights = np.array([2.0 ** -i for i in range(len(templates))])
@@ -131,7 +119,7 @@ def _events(n_queries: int, mutate_every: int, rows: int, seed: int
 
 def _cold_answers(query: Query, registry: TableRegistry) -> List[tuple]:
     tables = {t: registry[t].copy() for t in query.tables}
-    engine = ImputationService(tables, default=IMPUTER_FACTORIES[IMPUTER])
+    engine = ImputationService(tables, default=MeanImputer)
     return sorted(execute_quip(query, tables, engine, strategy=STRATEGY,
                                morsel_rows=MORSEL_ROWS).answer_tuples())
 
@@ -139,13 +127,11 @@ def _cold_answers(query: Query, registry: TableRegistry) -> List[tuple]:
 def _serve(events: List[Tuple], tables: Dict[str, MaskedRelation], *,
            ivm: bool, check_cold: bool) -> Dict:
     registry = TableRegistry({t: r.copy() for t, r in tables.items()})
-    svc = QuipService(
-        registry, IMPUTER_FACTORIES[IMPUTER], strategy=STRATEGY,
-        morsel_rows=MORSEL_ROWS, result_cache_size=128, ivm=ivm,
-    )
+    svc = QuipService(registry, MeanImputer, strategy=STRATEGY,
+                      morsel_rows=MORSEL_ROWS, result_cache_size=128,
+                      ivm=ivm)
     answers: List[List[tuple]] = []
-    queries = mutations = stale = 0
-    t0 = time.perf_counter()
+    stale = 0
     for event in events:
         if event[0] == "mutate":
             _kind, op, ids, payload = event
@@ -155,68 +141,45 @@ def _serve(events: List[Tuple], tables: Dict[str, MaskedRelation], *,
                 registry.delete_rows("R0", ids)
             else:
                 registry.insert_rows("R0", payload)
-            mutations += 1
             continue
         _kind, query = event
         got = sorted(svc.answers(svc.submit(query)))
         answers.append(got)
-        queries += 1
         if check_cold:
             stale += int(got != _cold_answers(query, registry))
-    wall = time.perf_counter() - t0
-    summary = svc.summary()
-    row = {
-        "mode": f"ivm_{'on' if ivm else 'off'}",
-        "queries": queries, "mutations": mutations,
-        "wall_s": round(wall, 4),
-        "result_cache_hits": summary["result_cache_hits"],
-        "queries_result_cache_hit": summary["queries_result_cache_hit"],
-        "results_patched": summary["results_patched"],
-        "ivm_fallbacks": summary["ivm_fallbacks"],
-        "results_invalidated": summary["results_invalidated"],
-        "imputations": summary["imputations"],
-        "stale_answers": stale,
-        "_answers": answers,
-    }
+    out = {"answers": answers, "stale_answers": stale, **svc.summary()}
     if ivm:
-        row["fallback_reasons"] = dict(svc._ivm.fallback_reasons)
-    return row
-
-
-def run(fast: bool = True) -> List[Dict]:
-    rows, n_queries = (1500, 60) if fast else (6000, 160)
-    tables = _instance(rows, missing_rate=0.25, seed=14)
-    events = _events(n_queries, mutate_every=4, rows=rows, seed=14)
-    out = [
-        _serve(events, tables, ivm=False, check_cold=False),
-        _serve(events, tables, ivm=True, check_cold=True),
-    ]
-    base = out[0].pop("_answers")
-    out[1]["answers_match_evicting"] = int(out[1].pop("_answers") == base)
+        out["fallback_reasons"] = dict(svc._ivm.fallback_reasons)
     return out
 
 
-def derived(rows: List[Dict]) -> Dict[str, float]:
-    by_mode = {r["mode"]: r for r in rows}
-    off, on = by_mode["ivm_off"], by_mode["ivm_on"]
-    # acceptance invariants (CI smoke) — deterministic counters only
-    assert on["results_patched"] > 0, (
-        f"IVM never patched: {on.get('fallback_reasons')}"
-    )
-    assert on["stale_answers"] == 0, "patched answer diverged from cold replay"
-    assert on["answers_match_evicting"] == 1, \
-        "IVM-on answers diverged from the evicting service"
-    assert on["queries_result_cache_hit"] > off["queries_result_cache_hit"], \
-        "patching produced no hit-rate gain over evicting"
-    assert off["results_patched"] == 0 and off["ivm_fallbacks"] == 0
+@pytest.fixture(scope="module")
+def ivm():
+    tables = _instance(ROWS, missing_rate=0.25, seed=14)
+    events = _events(60, mutate_every=4, rows=ROWS, seed=14)
     return {
-        "ivm_results_patched": on["results_patched"],
-        "ivm_fallbacks": on["ivm_fallbacks"],
-        "ivm_stale_answers": on["stale_answers"],
-        "ivm_hits": on["queries_result_cache_hit"],
-        "evicting_hits": off["queries_result_cache_hit"],
-        "ivm_hit_gain": (
-            on["queries_result_cache_hit"] - off["queries_result_cache_hit"]
-        ),
-        "ivm_imputations_saved": off["imputations"] - on["imputations"],
+        "off": _serve(events, tables, ivm=False, check_cold=False),
+        "on": _serve(events, tables, ivm=True, check_cold=True),
     }
+
+
+def test_ivm_patches_results(ivm):
+    assert ivm["on"]["results_patched"] > 0, ivm["on"]["fallback_reasons"]
+
+
+def test_patched_answers_match_cold_replay(ivm):
+    assert ivm["on"]["stale_answers"] == 0
+
+
+def test_patched_answers_match_evicting_service(ivm):
+    assert ivm["on"]["answers"] == ivm["off"]["answers"]
+
+
+def test_patching_gains_result_cache_hits(ivm):
+    assert (ivm["on"]["queries_result_cache_hit"]
+            > ivm["off"]["queries_result_cache_hit"])
+
+
+def test_evicting_service_patches_nothing(ivm):
+    assert ivm["off"]["results_patched"] == 0
+    assert ivm["off"]["ivm_fallbacks"] == 0

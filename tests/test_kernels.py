@@ -234,43 +234,3 @@ def test_neighbor_mode_tie_breaks_to_smallest_value():
         got = kops.neighbor_aggregate(neigh, categorical=True, impl=impl)
         np.testing.assert_array_equal(got, [2.0, 1.0], err_msg=f"impl={impl}")
 
-
-# --------------------------------------------------------------------------- #
-# flash attention kernel
-# --------------------------------------------------------------------------- #
-@pytest.mark.parametrize("b,s,h,kv,d", [
-    (1, 16, 2, 1, 8), (2, 64, 4, 2, 16), (1, 96, 8, 2, 32), (2, 100, 4, 4, 16),
-])
-@pytest.mark.parametrize("causal,window", [
-    (True, None), (False, None), (True, 24),
-])
-def test_flash_attention_pallas_matches_ref(b, s, h, kv, d, causal, window):
-    from repro.kernels.flash_attention import flash_attention_pallas
-
-    rng = np.random.default_rng(s * 10 + h)
-    q = jnp.asarray(rng.normal(size=(b, s, h, d)).astype(np.float32))
-    k = jnp.asarray(rng.normal(size=(b, s, kv, d)).astype(np.float32))
-    v = jnp.asarray(rng.normal(size=(b, s, kv, d)).astype(np.float32))
-    ref = kref.attention_ref(q, k, v, causal=causal, window=window)
-    out = flash_attention_pallas(
-        q, k, v, causal=causal, window=window, bq=32, bk=32, interpret=True
-    )
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4
-    )
-
-
-def test_flash_attention_pallas_bf16():
-    from repro.kernels.flash_attention import flash_attention_pallas
-
-    rng = np.random.default_rng(3)
-    b, s, h, kv, d = 1, 64, 4, 2, 16
-    q = jnp.asarray(rng.normal(size=(b, s, h, d))).astype(jnp.bfloat16)
-    k = jnp.asarray(rng.normal(size=(b, s, kv, d))).astype(jnp.bfloat16)
-    v = jnp.asarray(rng.normal(size=(b, s, kv, d))).astype(jnp.bfloat16)
-    ref = kref.attention_ref(q, k, v)
-    out = flash_attention_pallas(q, k, v, bq=32, bk=32, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(out, dtype=np.float32), np.asarray(ref, dtype=np.float32),
-        rtol=3e-2, atol=3e-2,
-    )
